@@ -1,8 +1,9 @@
 """BENCH: single-device vs 1-D sharded vs 2-D sharded, static + streamed DF-P.
 
-Forces a multi-device host platform (``--xla_force_host_platform_device_count``,
-the SNIPPETS.md idiom) in a **subprocess**, so the rest of the benchmark
-suite keeps seeing the real single device. Numbers on a shared CPU host
+Forces a multi-device host platform (``--xla_force_host_platform_device_count``)
+in a **subprocess** pinned to ``JAX_PLATFORMS=cpu``, so the rest of the
+benchmark suite keeps seeing the real single device and the child never
+contends for an accelerator. A failed child fails the run (non-zero exit). Numbers on a shared CPU host
 measure the *relationships* (collective overhead of 1-D vs 2-D vs none;
 incremental sharded maintenance vs O(|E|) re-partition), not absolute
 cluster performance.
@@ -124,6 +125,9 @@ SCRIPT = textwrap.dedent("""
 
 def run():
     env = dict(os.environ)
+    # the child is a CPU rehearsal on forced host devices: pinned to the
+    # CPU, it can never contend with this process for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEV}"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + (
@@ -132,9 +136,9 @@ def run():
                          capture_output=True, text=True, timeout=1800)
     from .common import emit
     if out.returncode != 0:
-        emit("distributed/FAILED", 0.0, "see-stderr")
         sys.stderr.write(out.stderr[-2000:])
-        return
+        raise SystemExit(f"bench_distributed: child exited "
+                         f"{out.returncode}")
     # re-emit the subprocess CSV through the shared sink so the rows land
     # in the structured report too (the subprocess has its own interpreter;
     # its RECORDS/registry are unreachable from here)

@@ -59,6 +59,9 @@ def main(argv=None) -> int:
                     help="report name recorded in the JSON header")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import common
     common.set_smoke(args.smoke)
     common.reset_records()

@@ -1,12 +1,17 @@
-"""Distributed PageRank on 8 (forced) host devices: 1-D vertex partition vs
-the beyond-paper 2-D edge partition, both validated against the oracle —
-plus a sharded StreamSession chaining DF-P over a live update stream
-(mirrors examples/streaming_pagerank.py at multi-device scale).
+"""CPU rehearsal of distributed PageRank on 8 forced host devices: 1-D
+vertex partition vs the beyond-paper 2-D edge partition, both validated
+against the oracle — plus a sharded StreamSession chaining DF-P over a live
+update stream (mirrors examples/streaming_pagerank.py at multi-device scale).
 
   PYTHONPATH=src python examples/distributed_pagerank.py
+
+It pins itself to the CPU (JAX_PLATFORMS=cpu): the 8 devices are virtual
+host devices, not chips. The sharded session on real chips is phase e of
+`python chip_smoke.py --chips 4`.
 """
 import os
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax

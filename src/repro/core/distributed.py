@@ -4,7 +4,7 @@
 ``n_loc = n_pad / nd`` vertices — their ELL rows, tile-padded CSR slices,
 ranks and affected flags. The pull model makes the per-iteration communication
 exactly one collective: ``all_gather`` of the contribution vector
-``c = R / outdeg`` (V·4 B), plus a scalar ``pmax`` for convergence — this is
+``c = R / outdeg`` (V·4 B), plus a replicated scalar max for convergence — this is
 the paper's "one write per vertex" discipline lifted to the cluster level
 (each device writes only its own rank slice; no cross-device scatter exists).
 
@@ -31,7 +31,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .dynamic import solve_health
 from .frontier import (FS_ACTIVE_ROWS, FS_ACTIVE_TILES, FS_COMPACT, FS_ITERS,
@@ -45,32 +45,20 @@ from .rank_step import rank_step
 from ..obs.spans import get_registry as _obs
 from ..obs.trace import trace_init, trace_record
 
-try:  # JAX >= 0.4.35 spelling
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
+def stacked_sharding(mesh: Mesh) -> NamedSharding:
+    """Placement of every stacked [nd, ...] array: the leading shard axis
+    split over all mesh axes (flattened), so shard s lives on device s —
+    the layout `shard_map` consumes with in_spec ``P(axis)``."""
+    return NamedSharding(mesh, P(tuple(mesh.axis_names)))
 
-def shard_map_loop(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map a while-loop body, portably across JAX versions.
-
-    JAX builds in the 0.4.3x line have no replication rule for `while` and
-    require `check_rep=False`; newer builds dropped the kwarg once the rule
-    existed. All convergence scalars here pass through `pmax` before the
-    loop predicate, so skipping the static replication check is sound.
-    """
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - kwarg removed in newer JAX
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
 
 __all__ = ["ShardedGraph", "build_sharded", "sharded_caps", "sharded_need",
            "shard_bounds", "shard_block_rows",
            "initial_affected_sharded", "shard_vector", "unshard_vector",
            "distributed_static_pagerank", "distributed_dfp_pagerank",
-           "sharded_frontier_caps", "pagerank_step_specs"]
+           "sharded_frontier_caps", "pagerank_step_specs",
+           "stacked_sharding"]
 
 
 class ShardedGraph(NamedTuple):
@@ -319,6 +307,15 @@ def _squeeze_shard(sgd: dict) -> dict:
     return jax.tree.map(lambda v: v[0], sgd)
 
 
+def _all_max(x: jnp.ndarray, axis) -> jnp.ndarray:
+    """Max of a per-shard scalar over the mesh, replicated. A one-hot psum
+    stands in for pmax: the TPU lowers only sum all-reduces in float64, and
+    adding zeros to the one non-zero lane is exact (NaN still propagates)."""
+    lanes = jnp.zeros((jax.lax.axis_size(axis),), x.dtype)
+    return jnp.max(jax.lax.psum(lanes.at[jax.lax.axis_index(axis)].set(x),
+                                axis))
+
+
 def _make_loop(axis, params: PRParams, n_true: int, *, dfp: bool,
                compact_frontier: bool = False, delta_every: int = 1,
                trace: bool = False, frontier_caps=None,
@@ -329,7 +326,7 @@ def _make_loop(axis, params: PRParams, n_true: int, *, dfp: bool,
     The per-iteration math is `core.rank_step.rank_step` on this shard's
     slice — the same single implementation the dense engine uses — wrapped
     in the two collectives the 1-D partition needs: the contribution
-    all-gather and the convergence pmax. Frontier expansion (dfp) pulls the
+    all-gather and the convergence max. Frontier expansion (dfp) pulls the
     gathered δ_N through the same local layout, *including at iteration 0*,
     which is the paper's initial expansion (line 9) performed device-side:
     callers seed δ_N with the updated sources (`initial_affected_sharded`)
@@ -400,7 +397,7 @@ def _make_loop(axis, params: PRParams, n_true: int, *, dfp: bool,
                 prune=dfp, closed_form=dfp, track_frontier=dfp)
             if not dfp:
                 dn_new = dn
-            gmax = jax.lax.pmax(local, axis)
+            gmax = _all_max(local, axis)
             if delta_every > 1:
                 check = (i + 1) % delta_every == 0
                 delta = jnp.where(check, gmax, jnp.asarray(jnp.inf, dt))
@@ -423,18 +420,22 @@ def _make_loop(axis, params: PRParams, n_true: int, *, dfp: bool,
         tb0 = trace_init(params.max_iter, dt,
                          "dfp_1d" if dfp else "static_1d") if trace \
             else jnp.asarray(0, jnp.int32)
-        nb = len(sgl["buckets"])
+        # the frontier stats accumulate per-shard counts (psum'd on exit),
+        # so the carry is varying over the mesh axes from its first value
+        fs0 = jax.lax.pcast(fstats_init(len(sgl["buckets"])), axis,
+                            to="varying")
         init = (r0, dv0, dn0, jnp.asarray(jnp.inf, dt),
-                jnp.asarray(0, jnp.int32), tb0, fstats_init(nb))
+                jnp.asarray(0, jnp.int32), tb0, fs0)
         r, dv, dn, delta, iters, tb, fs = jax.lax.while_loop(cond, body, init)
         out = [r[None], iters]
         if trace:
             out.append(tb)
         if health:
-            # guard.health word, replicated: delta came through pmax, the
-            # mass is one extra psum over the valid slice. A delta left at
-            # the inf skip-sentinel (delta_every>1 exhausting the budget
-            # between checks) clamps to H_MAX_ITER inside solve_health.
+            # guard.health word, replicated: delta came through
+            # `_all_max`, the mass is one extra psum over the valid slice.
+            # A delta left at the inf skip-sentinel (delta_every>1
+            # exhausting the budget between checks) clamps to H_MAX_ITER
+            # inside solve_health.
             mass = jax.lax.psum(jnp.sum(jnp.where(valid, r, 0)), axis)
             out.append(solve_health(delta, iters, mass, params))
         if frontier_caps is not None:
@@ -456,6 +457,31 @@ def pagerank_step_specs(mesh: Mesh):
     return shard, axis
 
 
+@functools.lru_cache(maxsize=None)
+def _solver(mesh: Mesh, params: PRParams, n_true: int, dfp: bool,
+            delta_every: int, trace: bool, frontier_caps, health: bool):
+    """The jitted shard_map'd loop for one (mesh, params, flags) signature.
+
+    Cached so every batch of a stream re-enters the same jitted callable:
+    building ``jax.jit`` around a fresh closure per solve would retrace
+    (and recompile) the loop on every call."""
+    axis, shard = _specs(mesh)
+    loop = _make_loop(axis, params, n_true, dfp=dfp,
+                      delta_every=delta_every, trace=trace,
+                      frontier_caps=frontier_caps, health=health)
+    out_specs = [shard, P()]
+    if trace:
+        out_specs.append(P())
+    if health:
+        out_specs.append(P())
+    if frontier_caps is not None:
+        out_specs.append(P())
+    return jax.jit(jax.shard_map(
+        loop, mesh=mesh,
+        in_specs=({k: shard for k in _FIELDS}, shard, shard, shard),
+        out_specs=tuple(out_specs)))
+
+
 def distributed_static_pagerank(mesh: Mesh, sg: ShardedGraph, r0: jnp.ndarray,
                                 params: PRParams = PRParams(),
                                 delta_every: int = 1, trace: bool = False,
@@ -463,22 +489,14 @@ def distributed_static_pagerank(mesh: Mesh, sg: ShardedGraph, r0: jnp.ndarray,
     """r0: [nd, n_loc] stacked ranks. Returns (ranks [nd, n_loc], iters),
     plus a replicated obs.trace.TraceBuffer when ``trace=True`` and a
     replicated guard.health word (last) when ``health=True``."""
-    axis, shard = _specs(mesh)
     nd, n_loc = sg.out_deg.shape
-    on = jnp.ones((nd, n_loc), jnp.bool_)
-    off = jnp.zeros((nd, n_loc), jnp.bool_)
-    loop = _make_loop(axis, params, sg.n_true, dfp=False,
-                      delta_every=delta_every, trace=trace, health=health)
-    out_specs = [shard, P()]
-    if trace:
-        out_specs.append(P())
-    if health:
-        out_specs.append(P())
-    fn = shard_map_loop(loop, mesh,
-                        ({k: shard for k in _FIELDS}, shard, shard, shard),
-                        tuple(out_specs))
+    where = stacked_sharding(mesh)
+    on = jnp.ones((nd, n_loc), jnp.bool_, device=where)
+    off = jnp.zeros((nd, n_loc), jnp.bool_, device=where)
+    fn = _solver(mesh, params, sg.n_true, False, delta_every, trace, None,
+                 health)
     with _obs().span("solve.static_1d", annotate=True):
-        return jax.jit(fn)(_as_dict(sg), r0, on, off)
+        return fn(_as_dict(sg), r0, on, off)
 
 
 def sharded_frontier_caps(sg: ShardedGraph, est: int,
@@ -508,22 +526,10 @@ def distributed_dfp_pagerank(mesh: Mesh, sg: ShardedGraph, r_prev: jnp.ndarray,
     ``frontier_caps`` (`sharded_frontier_caps`) compacts each shard's rank
     pull to its active rows/tiles — identical results, frontier.* obs
     counters published host-side."""
-    axis, shard = _specs(mesh)
-    loop = _make_loop(axis, params, sg.n_true, dfp=True,
-                      delta_every=delta_every, trace=trace,
-                      frontier_caps=frontier_caps, health=health)
-    out_specs = [shard, P()]
-    if trace:
-        out_specs.append(P())
-    if health:
-        out_specs.append(P())
-    if frontier_caps is not None:
-        out_specs.append(P())
-    fn = shard_map_loop(loop, mesh,
-                        ({k: shard for k in _FIELDS}, shard, shard, shard),
-                        tuple(out_specs))
+    fn = _solver(mesh, params, sg.n_true, True, delta_every, trace,
+                 frontier_caps, health)
     with _obs().span("solve.dfp_1d", annotate=True):
-        out = jax.jit(fn)(_as_dict(sg), r_prev, dv0, dn0)
+        out = fn(_as_dict(sg), r_prev, dv0, dn0)
     if frontier_caps is not None:
         *out, fs = out
         publish_fstats(fs)

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .distributed import shard_map_loop
 from .frontier import (FS_ACTIVE_ROWS, FS_COMPACT, FS_ITERS, FS_OVERFLOW,
                        fstats_init, publish_fstats, stream_compact)
 from .graph import Graph
@@ -255,9 +254,9 @@ def _run(mesh: Mesh, sg: Sharded2D, r0, dv0, dn0, params, dfp: bool,
         out_specs.append(P())
     if row_cap is not None:
         out_specs.append(P())
-    fn = shard_map_loop(loop, mesh,
-                        ({k: shard for k in sgd}, shard, shard, shard),
-                        tuple(out_specs))
+    fn = jax.shard_map(loop, mesh=mesh,
+                       in_specs=({k: shard for k in sgd}, shard, shard, shard),
+                       out_specs=tuple(out_specs))
     out = jax.jit(fn)(sgd, r0, dv0, dn0)
     if row_cap is not None:
         *out, fs = out

@@ -12,12 +12,15 @@ prefetch and drive the *output* index map (the Pallas idiom for a
 data-dependent scatter). Rows not visited by any program keep the aliased
 input contents. Duplicate row ids are permitted only when they carry
 identical contents — the pad convention is "repeat entry 0", which
-satisfies this by construction.
+satisfies this by construction. The [n, d] arrays are viewed as [n, 1, d]
+so each program's (1, d) block spans the array's last two dimensions
+whole, the block shape Mosaic accepts for any d.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -25,6 +28,11 @@ from ..obs.spans import get_registry as _obs
 from .common import default_interpret as _default_interpret
 
 __all__ = ["scatter_rows", "ell_scatter_rows"]
+
+
+# Block indices must be int32 for Mosaic; a bare 0 in an index map becomes an
+# int64 constant when x64 is on (the float64 session always runs with it).
+_ZERO = np.int32(0)
 
 
 def _copy_kernel(rows_ref, dst_ref, new_ref, out_ref):
@@ -39,7 +47,8 @@ def scatter_rows(dst: jnp.ndarray, rows: jnp.ndarray, new_rows: jnp.ndarray,
     dst: [n, d] ; rows: [K] int32 (pad by repeating rows[0]) ; new_rows: [K, d].
     """
     interpret = _default_interpret() if interpret is None else interpret
-    k, d = new_rows.shape
+    n, d = dst.shape
+    k = new_rows.shape[0]
     # trace-time only (the call site is jitted): counts kernel *builds*, and
     # rows are counted per build — re-executions of the cached computation
     # are invisible to host counters by design.
@@ -50,17 +59,19 @@ def scatter_rows(dst: jnp.ndarray, rows: jnp.ndarray, new_rows: jnp.ndarray,
         grid=(k,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),        # aliased, never read
-            pl.BlockSpec((1, d), lambda i, rows: (i, 0)),
+            pl.BlockSpec((None, 1, d), lambda i, rows: (i, _ZERO, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, rows: (rows[i], 0)),
+        out_specs=pl.BlockSpec((None, 1, d),
+                               lambda i, rows: (rows[i], _ZERO, _ZERO)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _copy_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), dst.dtype),
         input_output_aliases={1: 0},   # dst (after the prefetch arg) -> out
         interpret=interpret,
-    )(rows, dst, new_rows)
+    )(rows, dst.reshape(n, 1, d), new_rows.reshape(k, 1, d))
+    return out.reshape(n, d)
 
 
 def ell_scatter_rows(ell_idx: jnp.ndarray, ell_mask: jnp.ndarray,

@@ -155,10 +155,6 @@ def lower_pagerank(mesh, n_vertices=1_048_576, d_p=64, tile=1024,
     iteration (all-gather + hybrid pull + fused update) at |V|=1M, |E|~16M."""
     from ..core.distributed import _FIELDS, _make_loop
     from ..core.pagerank import EllBlock, PRParams
-    try:
-        from jax import shard_map as shard_map_fn
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as shard_map_fn
 
     nd = mesh.devices.size
     n_loc = n_vertices // nd
@@ -189,10 +185,10 @@ def lower_pagerank(mesh, n_vertices=1_048_576, d_p=64, tile=1024,
     flags = jax.ShapeDtypeStruct((nd, n_loc), jnp.bool_)
     loop = _make_loop(tuple(mesh.axis_names), PRParams(max_iter=1),
                       n_vertices, dfp=True, compact_frontier=opt)
-    fn = shard_map_fn(loop, mesh=mesh,
-                      in_specs=({k: shard for k in _FIELDS}, shard, shard,
-                                shard),
-                      out_specs=(shard, P()))
+    fn = jax.shard_map(loop, mesh=mesh,
+                       in_specs=({k: shard for k in _FIELDS}, shard, shard,
+                                 shard),
+                       out_specs=(shard, P()))
     with mesh:
         lowered = jax.jit(fn).lower(sgd, r, flags, flags)
         compiled = lowered.compile()
@@ -217,10 +213,6 @@ def lower_pagerank_2d(mesh, n_vertices=1_048_576, d_p=8, verbose=True):
     (data, model) = (16, 16) sub-mesh; 'pod' (if present) replicates."""
     from ..core.distributed2d import Sharded2D, _loop_2d
     from ..core.pagerank import PRParams
-    try:
-        from jax import shard_map as shard_map_fn
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as shard_map_fn
 
     axes = mesh.axis_names
     row_axis, col_axis = axes[-2], axes[-1]
@@ -241,9 +233,9 @@ def lower_pagerank_2d(mesh, n_vertices=1_048_576, d_p=8, verbose=True):
     fsh = jax.ShapeDtypeStruct((rc, blk), jnp.bool_)
     loop = _loop_2d(PRParams(max_iter=1), n_vertices, r, c, dfp=True,
                     row_axis=row_axis, col_axis=col_axis)
-    fn = shard_map_fn(loop, mesh=mesh,
-                      in_specs=({k: shard for k in sgd}, shard, shard, shard),
-                      out_specs=(shard, P()))
+    fn = jax.shard_map(loop, mesh=mesh,
+                       in_specs=({k: shard for k in sgd}, shard, shard, shard),
+                       out_specs=(shard, P()))
     with mesh:
         compiled = jax.jit(fn).lower(sgd, rsh, fsh, fsh).compile()
     rep = analyze(f"pagerank-dfp-2d/{n_vertices}v/"

@@ -40,6 +40,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.compact import df_pagerank_compact, dfp_pagerank_compact
 from ..core.distributed import (distributed_dfp_pagerank,
@@ -187,9 +188,8 @@ class StreamSession:
         self._snap_kw = dict(snap_kw)
         self._d_p, self._tile = d_p, tile
         if mesh is not None:
-            nd = int(mesh.devices.size)
             self.snap = snapshot if snapshot is not None else ShardedSnapshot(
-                g, nd=nd, d_p=d_p, tile=tile, **snap_kw)
+                g, mesh, d_p=d_p, tile=tile, **snap_kw)
         else:
             self.snap = snapshot if snapshot is not None else DeviceSnapshot(
                 g, d_p=d_p, tile=tile, **snap_kw)
@@ -694,12 +694,17 @@ class StreamSession:
             return static_pagerank(self.snap.dg, init_ranks(self.n),
                                    params, health=health)
         r0 = jnp.full((self.snap.nd, self.snap.n_loc), 1.0 / self.n,
-                      init_ranks(1).dtype)
+                      init_ranks(1).dtype, device=self.snap.sharding)
         return distributed_static_pagerank(self.mesh, self.snap.sg, r0,
                                            params, health=health)
 
     def _flatten(self, r: jnp.ndarray) -> jnp.ndarray:
-        return r if self.mesh is None else jnp.reshape(r, (-1,))[:self.n]
+        if self.mesh is None:
+            return r
+        # replicate first: slicing off the padding of a vector that is
+        # still split over the mesh is ambiguous under explicit sharding
+        r = jax.device_put(r, NamedSharding(self.mesh, P()))
+        return jnp.reshape(r, (-1,))[:self.n]
 
     def flat_ranks(self) -> jnp.ndarray:
         """Current ranks as a dense [n] vector regardless of session mode."""

@@ -31,15 +31,17 @@ budget, batch above the cost crossover) routes through
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.distributed import (ShardedGraph, shard_block_rows, shard_bounds,
-                                sharded_need)
+                                sharded_need, stacked_sharding)
 from ..core.graph import (Graph, build_hybrid_rows, choose_bucket_widths,
                           edge_keys, graph_from_sorted_keys, next_pow2)
 from ..core.pagerank import EllBlock
@@ -47,16 +49,30 @@ from ..obs.flight import get_flight
 from ..obs.spans import get_registry as _obs
 from .delta import Delta
 from .snapshot import (CapacityError, SnapshotStats, _HalfLayout, _pad_rows,
-                       _scatter_1d, apply_net_delta, rebuild_reason)
+                       apply_net_delta, rebuild_reason)
 
 __all__ = ["ShardedSnapshot"]
 
 
-@jax.jit
-def _scatter_shard_rows(arr, s, rows, vals):
-    """arr [nd, R, ...] <- vals at [s, rows]; rows pre-padded (duplicates OK,
-    padded lanes re-write identical values)."""
-    return arr.at[s, rows].set(vals)
+@functools.lru_cache(maxsize=None)
+def _stacked_scatter(mesh: Mesh):
+    """jit: arr [nd, R, ...] <- vals at flat row ids ``s * R + row``.
+
+    Runs under shard_map: each shard writes only the ids that fall in its
+    own block (the rest drop), so the result keeps arr's placement — every
+    shard stays on its own device and nothing is gathered. Ids may repeat
+    when the repeats carry identical values (the `_pad_rows` convention)."""
+    axis = tuple(mesh.axis_names)
+
+    def local(arr, at, vals):
+        rows = arr.shape[1]
+        r = at - jax.lax.axis_index(axis) * rows
+        r = jnp.where((r >= 0) & (r < rows), r, rows)
+        return arr.at[0, r].set(vals, mode="drop")
+
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axis), P(), P()),
+                                 out_specs=P(axis)))
 
 
 class ShardedSnapshot:
@@ -65,15 +81,19 @@ class ShardedSnapshot:
     Exposes `.sg` — the `ShardedGraph` the distributed engines accept — and
     the same `apply(delta) -> SnapshotStats` lifecycle as `DeviceSnapshot`.
     Vertex v lives on shard `v // n_loc` at local row `v % n_loc`
-    (contiguous blocks, identical to `build_sharded`).
+    (contiguous blocks, identical to `build_sharded`). Every stacked array
+    is placed with `stacked_sharding(mesh)` — shard s on device s — when it
+    is built, and the row scatters keep that placement.
     """
 
-    def __init__(self, g: Graph, nd: int, d_p: int = 64, tile: int = 256,
+    def __init__(self, g: Graph, mesh: Mesh, d_p: int = 64, tile: int = 256,
                  hi_headroom: float = 2.0, tile_headroom: float = 2.0,
                  rebuild_threshold: float = 0.05, frag_budget: float = 0.6,
                  low_water: Optional[int] = None):
         self.n = g.n
-        self.nd = nd
+        self.nd = nd = int(mesh.devices.size)
+        self.sharding = stacked_sharding(mesh)
+        self._scatter = _stacked_scatter(mesh)
         self.n_pad = ((g.n + nd - 1) // nd) * nd
         self.n_loc = self.n_pad // nd
         self.d_p, self.tile = d_p, tile
@@ -88,7 +108,7 @@ class ShardedSnapshot:
         # valid is static: the vertex set never changes across the stream
         valid = np.zeros(self.n_pad, bool)
         valid[:self.n] = True
-        self._dev_valid = jnp.asarray(valid.reshape(nd, self.n_loc))
+        self._dev_valid = self._put(valid.reshape(nd, self.n_loc))
         self._adopt(g)
         self._last_rebuild_reason = ""
 
@@ -136,29 +156,31 @@ class ShardedSnapshot:
             self._halves.append(half)
         self._restack()
 
+    def _put(self, a: np.ndarray) -> jnp.ndarray:
+        """Stage a stacked [nd, ...] host array, shard s on device s."""
+        return jax.device_put(a, self.sharding)
+
+    def _stack(self, field: str, bi: Optional[int] = None) -> jnp.ndarray:
+        # np.stack copies: the mirrors mutate in place across batches
+        return self._put(np.stack([
+            getattr(h, field) if bi is None else getattr(h, field)[bi]
+            for h in self._halves]))
+
     def _restack(self) -> None:
-        # stacked device residency (copies: the mirrors mutate in place) —
-        # adopt/rebuild and checkpoint-restore both end here
+        # stacked device residency — adopt/rebuild and checkpoint-restore
+        # both end here
         self.dev_buckets: List[EllBlock] = [
-            EllBlock(
-                rows=jnp.asarray(
-                    np.stack([h.bk_rows[bi] for h in self._halves])),
-                idx=jnp.asarray(
-                    np.stack([h.bk_idx[bi] for h in self._halves])),
-                mask=jnp.asarray(
-                    np.stack([h.bk_mask[bi] for h in self._halves])))
+            EllBlock(rows=self._stack("bk_rows", bi),
+                     idx=self._stack("bk_idx", bi),
+                     mask=self._stack("bk_mask", bi))
             for bi in range(len(self._caps["widths"]))]
-        self.dev_hi_tiles = jnp.asarray(
-            np.stack([h.hi_tiles for h in self._halves]))
-        self.dev_hi_tmask = jnp.asarray(
-            np.stack([h.hi_tmask for h in self._halves]))
-        self.dev_hi_rowmap = jnp.asarray(
-            np.stack([h.hi_rowmap for h in self._halves]))
-        self.dev_hi_pos = jnp.asarray(
-            np.stack([h.hi_ids for h in self._halves]))
+        self.dev_hi_tiles = self._stack("hi_tiles")
+        self.dev_hi_tmask = self._stack("hi_tmask")
+        self.dev_hi_rowmap = self._stack("hi_rowmap")
+        self.dev_hi_pos = self._stack("hi_ids")
         outdeg = np.ones(self.n_pad, np.int32)
         outdeg[:self.n] = self._outdeg
-        self._dev_outdeg = jnp.asarray(outdeg.reshape(self.nd, self.n_loc))
+        self._dev_outdeg = self._put(outdeg.reshape(self.nd, self.n_loc))
 
     def _rebuild(self, reason: str) -> None:
         caps = self._caps_for(self._indeg, widths=self._caps["widths"])
@@ -174,6 +196,14 @@ class ShardedSnapshot:
         )
         self._adopt(self.graph(), caps)
         self._last_rebuild_reason = reason
+
+    def _set_shard(self, arr: jnp.ndarray, s: int,
+                   vals: np.ndarray) -> jnp.ndarray:
+        """Replace shard s's whole [R] slice of a stacked [nd, R] table."""
+        rows = arr.shape[1]
+        return self._scatter(arr, jnp.arange(s * rows, (s + 1) * rows,
+                                             dtype=jnp.int32),
+                             jnp.asarray(vals.copy()))
 
     # -- queries -------------------------------------------------------------
 
@@ -281,54 +311,50 @@ class ShardedSnapshot:
             for s, half in enumerate(self._halves):
                 dirty = half.drain_dirty()
                 tiles = dirty["tiles"]
-                js = jnp.asarray(s)
                 for bi, slots in enumerate(dirty["bucket_slots"]):
+                    blk = self.dev_buckets[bi]
                     if slots.size:
                         at = _pad_rows(slots, next_pow2(slots.size))
-                        blk = self.dev_buckets[bi]
-                        new_idx = _scatter_shard_rows(
-                            blk.idx, js, jnp.asarray(at),
-                            jnp.asarray(half.bk_idx[bi][at]))
-                        new_mask = _scatter_shard_rows(
-                            blk.mask, js, jnp.asarray(at),
-                            jnp.asarray(half.bk_mask[bi][at]))
-                        self.dev_buckets[bi] = blk._replace(
-                            idx=new_idx, mask=new_mask)
+                        flat = jnp.asarray(s * blk.idx.shape[1] + at)
+                        blk = blk._replace(
+                            idx=self._scatter(blk.idx, flat, jnp.asarray(
+                                half.bk_idx[bi][at])),
+                            mask=self._scatter(blk.mask, flat, jnp.asarray(
+                                half.bk_mask[bi][at])))
                         obs.inc("snapshot.shard_scatters")
                         stats.rows_touched += int(slots.size)
                     # bucket row-id maps, restaged per shard only on
                     # migration (they are small: [cap_b])
                     if dirty["bucket_maps"][bi]:
-                        blk = self.dev_buckets[bi]
-                        self.dev_buckets[bi] = blk._replace(
-                            rows=blk.rows.at[s].set(
-                                jnp.asarray(half.bk_rows[bi].copy())))
+                        blk = blk._replace(rows=self._set_shard(
+                            blk.rows, s, half.bk_rows[bi]))
+                    self.dev_buckets[bi] = blk
                 if tiles.size:
                     at = _pad_rows(tiles, next_pow2(tiles.size))
-                    self.dev_hi_tiles = _scatter_shard_rows(
-                        self.dev_hi_tiles, js, jnp.asarray(at),
+                    flat = jnp.asarray(s * self.dev_hi_tiles.shape[1] + at)
+                    self.dev_hi_tiles = self._scatter(
+                        self.dev_hi_tiles, flat,
                         jnp.asarray(half.hi_tiles[at]))
-                    self.dev_hi_tmask = _scatter_shard_rows(
-                        self.dev_hi_tmask, js, jnp.asarray(at),
+                    self.dev_hi_tmask = self._scatter(
+                        self.dev_hi_tmask, flat,
                         jnp.asarray(half.hi_tmask[at]))
                     obs.inc("snapshot.shard_scatters")
                 # small per-shard 1-D side tables, restaged only when touched
                 if dirty["rowmap_dirty"]:
-                    self.dev_hi_rowmap = self.dev_hi_rowmap.at[s].set(
-                        jnp.asarray(half.hi_rowmap.copy()))
+                    self.dev_hi_rowmap = self._set_shard(
+                        self.dev_hi_rowmap, s, half.hi_rowmap)
                 if dirty["side_dirty"]:
-                    self.dev_hi_pos = self.dev_hi_pos.at[s].set(
-                        jnp.asarray(half.hi_ids.copy()))
+                    self.dev_hi_pos = self._set_shard(
+                        self.dev_hi_pos, s, half.hi_ids)
                 stats.tiles_touched += int(tiles.size)
             touched = np.unique(np.concatenate([d_s, i_s]))
             if touched.size:
+                # global vertex id == flat row id of the stacked [nd, n_loc]
                 at = _pad_rows(touched.astype(np.int32),
                                next_pow2(touched.size))
-                flat = self._dev_outdeg.reshape(-1)
-                flat = _scatter_1d(
-                    flat, jnp.asarray(at),
+                self._dev_outdeg = self._scatter(
+                    self._dev_outdeg, jnp.asarray(at),
                     jnp.asarray(self._outdeg[at].astype(np.int32)))
-                self._dev_outdeg = flat.reshape(self.nd, self.n_loc)
         obs.inc("snapshot.inplace_batches")
         obs.inc("snapshot.rows_touched", stats.rows_touched)
         obs.inc("snapshot.tiles_touched", stats.tiles_touched)

@@ -63,7 +63,7 @@ SCRIPT = textwrap.dedent("""
     def pull_all(sg):
         d = _as_dict(sg)
         return np.stack([np.asarray(_local_pull(
-            jax.tree.map(lambda v: v[s], d), jnp.asarray(x)))
+            jax.tree.map(lambda v: np.asarray(v)[s], d), jnp.asarray(x)))
             for s in range(snap.nd)])
     np.testing.assert_allclose(pull_all(snap.sg), pull_all(fresh),
                                rtol=1e-12)
