@@ -64,13 +64,13 @@ def _stacked_scatter(mesh: Mesh):
     when the repeats carry identical values (the `_pad_rows` convention)."""
     axis = tuple(mesh.axis_names)
 
-    def local(arr, at, vals):
+    def scatter_shard(arr, at, vals):
         rows = arr.shape[1]
         r = at - jax.lax.axis_index(axis) * rows
         r = jnp.where((r >= 0) & (r < rows), r, rows)
         return arr.at[0, r].set(vals, mode="drop")
 
-    return jax.jit(jax.shard_map(local, mesh=mesh,
+    return jax.jit(jax.shard_map(scatter_shard, mesh=mesh,
                                  in_specs=(P(axis), P(), P()),
                                  out_specs=P(axis)))
 
@@ -201,9 +201,8 @@ class ShardedSnapshot:
                    vals: np.ndarray) -> jnp.ndarray:
         """Replace shard s's whole [R] slice of a stacked [nd, R] table."""
         rows = arr.shape[1]
-        return self._scatter(arr, jnp.arange(s * rows, (s + 1) * rows,
-                                             dtype=jnp.int32),
-                             jnp.asarray(vals.copy()))
+        return self._scatter(arr, jnp.asarray(np.arange(
+            s * rows, (s + 1) * rows, dtype=np.int32)), jnp.asarray(vals.copy()))
 
     # -- queries -------------------------------------------------------------
 
