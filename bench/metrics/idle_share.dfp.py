@@ -1,0 +1,8 @@
+"""idle_share.dfp: percent of the traced window of a stream cell in which no
+operation ran on the device (bench/trace_reduce.py)."""
+
+
+def read(run):
+    if run.trace is None or not run.batches:
+        return None
+    return 100.0 * run.trace.idle_share
