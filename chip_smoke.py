@@ -205,7 +205,7 @@ def run_batches(sess, batches, counter, tag: str) -> list:
         st = sess.history[-1]
         print(f"[{tag}] batch {t} size={st.batch_size} engine={st.engine} "
               f"iters={st.iters} ingest_s={st.ingest_s:.4f} "
-              f"snapshot_s={st.snapshot.host_s + st.snapshot.device_s:.4f} "
+              f"snapshot_host_s={st.snapshot.host_s:.4f} "
               f"solve_s={st.solve_s:.4f} total_s={st.total_s:.4f} "
               f"rebuilt={st.snapshot.rebuilt}", flush=True)
         check_batch(st, r)

@@ -33,7 +33,7 @@ def main():
                else f"  L1 vs from-scratch: {rec.l1_vs_static:.2e}")
         print(f"batch {rec.t:2d}: |Δ|={h.batch_size:5d}  engine={h.engine:7s}"
               f"  iters={h.iters:3d}  maintain="
-              f"{(h.ingest_s + h.snapshot.host_s + h.snapshot.device_s) * 1e3:6.1f}ms"
+              f"{(h.ingest_s + h.snapshot.host_s) * 1e3:6.1f}ms"
               f"  solve={h.solve_s * 1e3:6.1f}ms{err}")
 
     ids, vals = sess.topk(5)

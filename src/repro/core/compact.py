@@ -51,7 +51,8 @@ def _scatter_expand(fwd: DeviceGraph, dn_flags: jnp.ndarray, kn: int
     """Paper Alg. 5 expandAffected, compacted (core.frontier.push_expand):
     out-neighbors of flagged vertices get marked. Returns a dense bool [n]
     of newly-marked vertices (complete only while Σδ_N ≤ kn)."""
-    return push_expand(fwd, dn_flags, kn)[0]
+    with jax.named_scope("pr.expand"):
+        return push_expand(fwd, dn_flags, kn)[0]
 
 
 @functools.partial(jax.jit,
@@ -122,34 +123,52 @@ def _compact_loop(dg: DeviceGraph, fwd: DeviceGraph, r0, dv0, dn0,
 def _df_like_compact(dg, fwd, r_prev, batch: DeviceBatch,
                      params: PRParams, *, prune: bool, headroom: int = 16,
                      trace: bool = False, health: bool = False):
+    """The compact loop, then the dense driver if the frontier outgrew its
+    capacity. Host steps run under annotated ``compact.*`` spans; the
+    ``compact.*`` counters (batches, overflows, the loop's own sweeps, the
+    planned capacity) take only values the host already reads."""
+    obs = _obs()
     n = dg.n
-    dv, dn = initial_affected(n, batch.del_src, batch.del_dst, batch.ins_src)
-    # initial marking via the compacted out-edge walk (paper Alg. 5), not a
-    # dense O(|E|) pull — the batch is tiny relative to the graph
-    kn_init = plan_capacity(int(jnp.sum(dn)) + 1, n, headroom=2)
-    dv = dv | _scatter_expand(fwd, dn, kn_init)
-    n_init = int(jnp.sum(dv)) + 1
-    k = plan_capacity(n_init, n, headroom=headroom)
-    kn = k
-    # No tile compaction: affected hubs legitimately need their full tile
-    # lists, and the high side is a small fraction of total edge slots —
-    # the ELL (low-degree majority) is where compaction pays (tile
-    # truncation forced immediate dense fallback on power-law graphs,
-    # refuting the tile-compaction hypothesis — DESIGN.md §4).
-    kt = dg.hi_tiles.shape[0]
-    dn0 = jnp.zeros((n,), jnp.bool_)
+    with obs.span("compact.plan", annotate=True):
+        dv, dn = initial_affected(n, batch.del_src, batch.del_dst,
+                                  batch.ins_src)
+        # initial marking via the compacted out-edge walk (paper Alg. 5),
+        # not a dense O(|E|) pull — the batch is tiny relative to the graph
+        kn_init = plan_capacity(int(jnp.sum(dn)) + 1, n, headroom=2)
+        dv = dv | _scatter_expand(fwd, dn, kn_init)
+        n_init = int(jnp.sum(dv)) + 1
+        k = plan_capacity(n_init, n, headroom=headroom)
+        kn = k
+        # No tile compaction: affected hubs legitimately need their full
+        # tile lists, and the high side is a small fraction of total edge
+        # slots — the ELL (low-degree majority) is where compaction pays
+        # (tile truncation forced immediate dense fallback on power-law
+        # graphs, refuting the tile-compaction hypothesis — DESIGN.md §4).
+        kt = dg.hi_tiles.shape[0]
+        dn0 = jnp.zeros((n,), jnp.bool_)
     r, dv, dn, delta, iters, tb = _compact_loop(dg, fwd, r_prev, dv, dn0,
                                                 params, k, kt, kn, prune,
                                                 trace)
+    with obs.span("compact.check", annotate=True):
+        # int(iters) is read on both branches (compact.sweeps); where the
+        # loop converged, the caller's later int() of the same array takes
+        # the host copy JAX keeps on it, so no transfer is added
+        delta_h, sweeps = float(delta), int(iters)
+    overflow = delta_h > params.tau and sweeps < params.max_iter
+    obs.inc("compact.batches")
+    obs.inc("compact.overflows", int(overflow))
+    obs.inc("compact.sweeps", sweeps)
+    obs.inc("compact.capacity", k)
     hw = None
-    if float(delta) > params.tau and int(iters) < params.max_iter:
+    if overflow:
         # frontier outgrew the capacity: dense engine finishes the job,
         # appending to the same trace buffer at offset `iters`. Its health
         # word (budget = the REMAINING iterations) is the solve's health
         # word: exhausting `rest` is exactly exhausting the total budget.
-        rest = params._replace(max_iter=params.max_iter - int(iters))
-        out = list(_dense_finish(dg, r, dv, dn, rest, prune, tb,
-                                 jnp.asarray(int(iters), jnp.int32), health))
+        rest = params._replace(max_iter=params.max_iter - sweeps)
+        with obs.span("compact.finish", annotate=True):
+            out = list(_dense_finish(dg, r, dv, dn, rest, prune, tb,
+                                     jnp.asarray(sweeps, jnp.int32), health))
         if health:
             hw = out.pop()
         r, it2 = out[0], out[1]

@@ -55,10 +55,11 @@ def solve_health(delta, iters, mass, params: PRParams,
     (compact-engine overflow, distributed delta_every skip), not a number —
     clamp it finite so it reads as H_MAX_ITER, not H_NONFINITE; NaN (real
     poisoning) passes through untouched."""
-    dt = jnp.asarray(delta).dtype
-    delta = jnp.where(jnp.isposinf(delta), jnp.finfo(dt).max, delta)
-    return health_word(delta, iters, mass, tau=params.tau,
-                       max_iter=params.max_iter, mass_tol=mass_tol)
+    with jax.named_scope("pr.converge"):
+        dt = jnp.asarray(delta).dtype
+        delta = jnp.where(jnp.isposinf(delta), jnp.finfo(dt).max, delta)
+        return health_word(delta, iters, mass, tau=params.tau,
+                           max_iter=params.max_iter, mass_tol=mass_tol)
 
 
 def _loop(dg: DeviceGraph, r0: jnp.ndarray, dv0: jnp.ndarray,

@@ -76,8 +76,9 @@ def expand_affected(dg: DeviceGraph, dv: jnp.ndarray, dn: jnp.ndarray
     engines use this only as the worklist-overflow fallback; see
     `expand_frontier`.
     """
-    pulled = pull_max(dg, dn.astype(jnp.float32))
-    return dv | (pulled > 0.5)
+    with jax.named_scope("pr.expand"):
+        pulled = pull_max(dg, dn.astype(jnp.float32))
+        return dv | (pulled > 0.5)
 
 
 def reach_affected(dg: DeviceGraph, seeds: jnp.ndarray,
@@ -119,13 +120,14 @@ def stream_compact(flags: jnp.ndarray, k: int, fill: int
     is the weakest op). Destinations beyond k are dropped (callers must
     treat count > k as overflow — the list is then truncated). Dead lanes
     hold `fill`. Returns (idx [k] int32, count)."""
-    ln = flags.shape[0]
-    keys = jnp.where(flags, jnp.arange(ln, dtype=jnp.int32), ln)
-    if k > ln:                      # caps may overshoot short flag vectors
-        keys = jnp.pad(keys, (0, k - ln), constant_values=ln)
-    idx = jax.lax.sort(keys, is_stable=False)[:k]
-    idx = jnp.where(idx >= ln, fill, idx)
-    return idx, jnp.sum(flags, dtype=jnp.int32)
+    with jax.named_scope("pr.compact"):
+        ln = flags.shape[0]
+        keys = jnp.where(flags, jnp.arange(ln, dtype=jnp.int32), ln)
+        if k > ln:                      # caps may overshoot short flag vectors
+            keys = jnp.pad(keys, (0, k - ln), constant_values=ln)
+        idx = jax.lax.sort(keys, is_stable=False)[:k]
+        idx = jnp.where(idx >= ln, fill, idx)
+        return idx, jnp.sum(flags, dtype=jnp.int32)
 
 
 class FrontierCaps(NamedTuple):
@@ -213,28 +215,29 @@ def active_frontier(buckets, hi_ids: jnp.ndarray, hi_rowmap: jnp.ndarray,
     gathering δ_V at the bucket's row ids (sentinel rows read False), the
     active tile list by gathering the hi-slot activity through the
     tile→slot map — no vertex-id→slot tables needed."""
-    assert len(caps.bucket) == len(buckets), \
-        "FrontierCaps bucket arity != layout bucket arity"
-    sels, counts = [], []
-    overflow = jnp.asarray(False)
-    for blk, kb in zip(buckets, caps.bucket):
-        on = jnp.take(dv, blk.rows, mode="fill", fill_value=False)
-        sel, cnt = stream_compact(on, kb, blk.rows.shape[0])
-        sels.append(sel)
-        counts.append(cnt)
-        overflow = overflow | (cnt > kb)
-    on_hi = jnp.take(dv, hi_ids, mode="fill", fill_value=False)
-    hi_sel, hi_cnt = stream_compact(on_hi, caps.hi, hi_ids.shape[0])
-    tile_on = jnp.take(on_hi, hi_rowmap)
-    tile_sel, t_cnt = stream_compact(tile_on, caps.tiles,
-                                     hi_rowmap.shape[0])
-    overflow = overflow | (hi_cnt > caps.hi) | (t_cnt > caps.tiles)
-    bucket_counts = (jnp.stack(counts) if counts
-                     else jnp.zeros((0,), jnp.int32))
-    n_rows = (jnp.sum(bucket_counts, dtype=jnp.int32) if counts
-              else jnp.asarray(0, jnp.int32)) + hi_cnt
-    return ActiveFrontier(tuple(sels), hi_sel, tile_sel, bucket_counts,
-                          n_rows, t_cnt, overflow)
+    with jax.named_scope("pr.compact"):
+        assert len(caps.bucket) == len(buckets), \
+            "FrontierCaps bucket arity != layout bucket arity"
+        sels, counts = [], []
+        overflow = jnp.asarray(False)
+        for blk, kb in zip(buckets, caps.bucket):
+            on = jnp.take(dv, blk.rows, mode="fill", fill_value=False)
+            sel, cnt = stream_compact(on, kb, blk.rows.shape[0])
+            sels.append(sel)
+            counts.append(cnt)
+            overflow = overflow | (cnt > kb)
+        on_hi = jnp.take(dv, hi_ids, mode="fill", fill_value=False)
+        hi_sel, hi_cnt = stream_compact(on_hi, caps.hi, hi_ids.shape[0])
+        tile_on = jnp.take(on_hi, hi_rowmap)
+        tile_sel, t_cnt = stream_compact(tile_on, caps.tiles,
+                                         hi_rowmap.shape[0])
+        overflow = overflow | (hi_cnt > caps.hi) | (t_cnt > caps.tiles)
+        bucket_counts = (jnp.stack(counts) if counts
+                         else jnp.zeros((0,), jnp.int32))
+        n_rows = (jnp.sum(bucket_counts, dtype=jnp.int32) if counts
+                  else jnp.asarray(0, jnp.int32)) + hi_cnt
+        return ActiveFrontier(tuple(sels), hi_sel, tile_sel, bucket_counts,
+                              n_rows, t_cnt, overflow)
 
 
 def active_pull_sum(buckets, hi_ids, hi_tiles, hi_tmask, hi_rowmap,
@@ -248,22 +251,23 @@ def active_pull_sum(buckets, hi_ids, hi_tiles, hi_tmask, hi_rowmap,
 
     Only valid when `af.overflow` is False (truncated lists would silently
     drop in-edges of hubs)."""
-    dt = c.dtype
-    out = jnp.zeros((n_out,), dt)
-    for blk, sel in zip(buckets, af.bucket_sel):
-        rows = jnp.take(blk.rows, sel, mode="fill", fill_value=n_out)
-        idx = jnp.take(blk.idx, sel, axis=0, mode="fill", fill_value=0)
-        msk = jnp.take(blk.mask, sel, axis=0, mode="fill", fill_value=0.0)
-        sums = jnp.sum(jnp.take(c, idx, axis=0) * msk.astype(dt), axis=1)
-        out = out.at[rows].add(sums, mode="drop")
-    tiles = jnp.take(hi_tiles, af.tile_sel, axis=0, mode="fill",
-                     fill_value=0)
-    tmask = jnp.take(hi_tmask, af.tile_sel, axis=0, mode="fill",
-                     fill_value=0.0)
-    tsums = jnp.sum(jnp.take(c, tiles, axis=0) * tmask.astype(dt), axis=1)
-    slot = jnp.take(hi_rowmap, af.tile_sel, mode="fill", fill_value=0)
-    owner = jnp.take(hi_ids, slot)        # dead lanes add 0.0 — inert
-    return out.at[owner].add(tsums, mode="drop")
+    with jax.named_scope("pr.pull"):
+        dt = c.dtype
+        out = jnp.zeros((n_out,), dt)
+        for blk, sel in zip(buckets, af.bucket_sel):
+            rows = jnp.take(blk.rows, sel, mode="fill", fill_value=n_out)
+            idx = jnp.take(blk.idx, sel, axis=0, mode="fill", fill_value=0)
+            msk = jnp.take(blk.mask, sel, axis=0, mode="fill", fill_value=0.0)
+            sums = jnp.sum(jnp.take(c, idx, axis=0) * msk.astype(dt), axis=1)
+            out = out.at[rows].add(sums, mode="drop")
+        tiles = jnp.take(hi_tiles, af.tile_sel, axis=0, mode="fill",
+                         fill_value=0)
+        tmask = jnp.take(hi_tmask, af.tile_sel, axis=0, mode="fill",
+                         fill_value=0.0)
+        tsums = jnp.sum(jnp.take(c, tiles, axis=0) * tmask.astype(dt), axis=1)
+        slot = jnp.take(hi_rowmap, af.tile_sel, mode="fill", fill_value=0)
+        owner = jnp.take(hi_ids, slot)        # dead lanes add 0.0 — inert
+        return out.at[owner].add(tsums, mode="drop")
 
 
 def update_ranks_active(dg: DeviceGraph, r: jnp.ndarray, dv: jnp.ndarray,
@@ -299,35 +303,36 @@ def push_expand(fwd: DeviceGraph, dn: jnp.ndarray, kn: int,
     walk gated by the activity mask — never overflows). Work is
     Σ out-degree(worklist), Alg. 5's bound. Returns (marks [n] bool,
     overflow) — marks are only complete when overflow is False."""
-    n = fwd.n
-    src, n_src = stream_compact(dn, kn, n)
-    overflow = n_src > kn
-    nb = len(fwd.buckets)
-    b_of = jnp.take(fwd.bucket_of, src, mode="fill", fill_value=nb)
-    s_of = jnp.take(fwd.slot_of, src, mode="fill", fill_value=0)
-    out = jnp.zeros((n + 1,), jnp.bool_)
-    for bi, blk in enumerate(fwd.buckets):
-        slot = jnp.where(b_of == bi, s_of, blk.rows.shape[0])
-        nbr = jnp.take(blk.idx, slot, axis=0, mode="fill", fill_value=0)
-        msk = jnp.take(blk.mask, slot, axis=0, mode="fill", fill_value=0.0)
-        tgt = jnp.where(msk > 0, nbr, n)
-        out = out.at[tgt.reshape(-1)].set(True, mode="drop")
-    # high-out-degree worklist entries: their tile lists
-    hi_aff = jnp.take(dn, fwd.hi_ids, mode="fill", fill_value=False)
-    tile_on = jnp.take(hi_aff, fwd.hi_rowmap)
-    if kt:
-        tsel, n_t = stream_compact(tile_on, kt, fwd.hi_tiles.shape[0])
-        overflow = overflow | (n_t > kt)
-        tiles = jnp.take(fwd.hi_tiles, tsel, axis=0, mode="fill",
-                         fill_value=0)
-        tmask = jnp.take(fwd.hi_tmask, tsel, axis=0, mode="fill",
-                         fill_value=0.0)
-        tgt2 = jnp.where(tmask > 0, tiles, n)
-    else:
-        tgt2 = jnp.where((fwd.hi_tmask > 0) & tile_on[:, None],
-                         fwd.hi_tiles, n)
-    out = out.at[tgt2.reshape(-1)].set(True, mode="drop")
-    return out[:n], overflow
+    with jax.named_scope("pr.expand"):
+        n = fwd.n
+        src, n_src = stream_compact(dn, kn, n)
+        overflow = n_src > kn
+        nb = len(fwd.buckets)
+        b_of = jnp.take(fwd.bucket_of, src, mode="fill", fill_value=nb)
+        s_of = jnp.take(fwd.slot_of, src, mode="fill", fill_value=0)
+        out = jnp.zeros((n + 1,), jnp.bool_)
+        for bi, blk in enumerate(fwd.buckets):
+            slot = jnp.where(b_of == bi, s_of, blk.rows.shape[0])
+            nbr = jnp.take(blk.idx, slot, axis=0, mode="fill", fill_value=0)
+            msk = jnp.take(blk.mask, slot, axis=0, mode="fill", fill_value=0.0)
+            tgt = jnp.where(msk > 0, nbr, n)
+            out = out.at[tgt.reshape(-1)].set(True, mode="drop")
+        # high-out-degree worklist entries: their tile lists
+        hi_aff = jnp.take(dn, fwd.hi_ids, mode="fill", fill_value=False)
+        tile_on = jnp.take(hi_aff, fwd.hi_rowmap)
+        if kt:
+            tsel, n_t = stream_compact(tile_on, kt, fwd.hi_tiles.shape[0])
+            overflow = overflow | (n_t > kt)
+            tiles = jnp.take(fwd.hi_tiles, tsel, axis=0, mode="fill",
+                             fill_value=0)
+            tmask = jnp.take(fwd.hi_tmask, tsel, axis=0, mode="fill",
+                             fill_value=0.0)
+            tgt2 = jnp.where(tmask > 0, tiles, n)
+        else:
+            tgt2 = jnp.where((fwd.hi_tmask > 0) & tile_on[:, None],
+                             fwd.hi_tiles, n)
+        out = out.at[tgt2.reshape(-1)].set(True, mode="drop")
+        return out[:n], overflow
 
 
 def expand_frontier(dg: DeviceGraph, fwd: DeviceGraph, dv: jnp.ndarray,
@@ -337,27 +342,28 @@ def expand_frontier(dg: DeviceGraph, fwd: DeviceGraph, dv: jnp.ndarray,
     dense pull (`expand_affected`) otherwise — chosen per iteration inside
     the jitted loop, so a one-off frontier spike costs one full sweep, not a
     recompile. Returns (δ_V', stats [work, pushed, pulled] int32)."""
-    n_dn = jnp.sum(dn, dtype=jnp.int32)
-    hi_aff = jnp.take(dn, fwd.hi_ids, mode="fill", fill_value=False)
-    n_t = jnp.sum(jnp.take(hi_aff, fwd.hi_rowmap), dtype=jnp.int32)
-    ovf = n_dn > caps.dn
-    if caps.fwd_tiles:
-        ovf = ovf | (n_t > caps.fwd_tiles)
+    with jax.named_scope("pr.expand"):
+        n_dn = jnp.sum(dn, dtype=jnp.int32)
+        hi_aff = jnp.take(dn, fwd.hi_ids, mode="fill", fill_value=False)
+        n_t = jnp.sum(jnp.take(hi_aff, fwd.hi_rowmap), dtype=jnp.int32)
+        ovf = n_dn > caps.dn
+        if caps.fwd_tiles:
+            ovf = ovf | (n_t > caps.fwd_tiles)
 
-    def pull_branch():
-        return expand_affected(dg, dv, dn)
+        def pull_branch():
+            return expand_affected(dg, dv, dn)
 
-    def push_branch():
-        marks, _ = push_expand(fwd, dn, caps.dn, caps.fwd_tiles)
-        return dv | marks
+        def push_branch():
+            marks, _ = push_expand(fwd, dn, caps.dn, caps.fwd_tiles)
+            return dv | marks
 
-    dv_new = jax.lax.cond(ovf, pull_branch, push_branch)
-    one = jnp.asarray(1, jnp.int32)
-    zero = jnp.asarray(0, jnp.int32)
-    stats = jnp.stack([n_dn,
-                       jnp.where(ovf, zero, one),
-                       jnp.where(ovf, one, zero)])
-    return dv_new, stats
+        dv_new = jax.lax.cond(ovf, pull_branch, push_branch)
+        one = jnp.asarray(1, jnp.int32)
+        zero = jnp.asarray(0, jnp.int32)
+        stats = jnp.stack([n_dn,
+                           jnp.where(ovf, zero, one),
+                           jnp.where(ovf, one, zero)])
+        return dv_new, stats
 
 
 # ---------------------------------------------------------------------------

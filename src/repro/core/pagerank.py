@@ -135,40 +135,44 @@ def pull_sum(dg: DeviceGraph, c: jnp.ndarray) -> jnp.ndarray:
     bucket's row map. CSR side: [t_cap, tile] masked gather + tile-sum +
     segment-sum over the tile->row map (tile-loop-per-vertex with an
     on-chip accumulator on TPU), scattered once into the dense result
-    (drop-mode handles pad sentinels on both sides).
+    (drop-mode handles pad sentinels on both sides). Its device ops carry
+    the stage name ``pr.pull`` (a ``jax.named_scope``: op metadata only).
     """
-    dt = c.dtype
-    out = jnp.zeros(c.shape, dt)
-    for blk in dg.buckets:
-        sums = jnp.sum(jnp.take(c, blk.idx, axis=0) * blk.mask.astype(dt),
-                       axis=1)
-        out = out.at[blk.rows].add(sums, mode="drop")
-    tile_sums = jnp.sum(jnp.take(c, dg.hi_tiles, axis=0) * dg.hi_tmask.astype(dt), axis=1)
-    hi_per_slot = jax.ops.segment_sum(tile_sums, dg.hi_rowmap,
-                                      num_segments=dg.n_hi_cap)
-    out = out.at[dg.hi_ids].add(hi_per_slot, mode="drop")
-    return out
+    with jax.named_scope("pr.pull"):
+        dt = c.dtype
+        out = jnp.zeros(c.shape, dt)
+        for blk in dg.buckets:
+            sums = jnp.sum(jnp.take(c, blk.idx, axis=0)
+                           * blk.mask.astype(dt), axis=1)
+            out = out.at[blk.rows].add(sums, mode="drop")
+        tile_sums = jnp.sum(jnp.take(c, dg.hi_tiles, axis=0)
+                            * dg.hi_tmask.astype(dt), axis=1)
+        hi_per_slot = jax.ops.segment_sum(tile_sums, dg.hi_rowmap,
+                                          num_segments=dg.n_hi_cap)
+        return out.at[dg.hi_ids].add(hi_per_slot, mode="drop")
 
 
 def pull_max(dg: DeviceGraph, x: jnp.ndarray) -> jnp.ndarray:
     """max_{u in G'.row(v)} x[u] — pull-based frontier expansion primitive.
 
     Replaces the paper's scatter-based `expandAffected` kernel pair (TPU has no
-    cheap scatter); same fixpoint, same schedule, scatter-free.
+    cheap scatter); same fixpoint, same schedule, scatter-free. Stage name
+    ``pr.expand``.
     """
-    dt = x.dtype
-    out = jnp.zeros(x.shape, dt)
-    for blk in dg.buckets:
-        rmax = jnp.max(jnp.take(x, blk.idx, axis=0) * blk.mask.astype(dt),
-                       axis=1, initial=0)
-        out = out.at[blk.rows].max(rmax, mode="drop")
-    tile_max = jnp.max(jnp.take(x, dg.hi_tiles, axis=0)
-                       * dg.hi_tmask.astype(dt), axis=1, initial=0)
-    hi_per_slot = jax.ops.segment_max(tile_max, dg.hi_rowmap,
-                                      num_segments=dg.n_hi_cap)
-    hi_per_slot = jnp.maximum(hi_per_slot, 0)  # empty segments -> -inf guard
-    out = out.at[dg.hi_ids].max(hi_per_slot, mode="drop")
-    return out
+    with jax.named_scope("pr.expand"):
+        dt = x.dtype
+        out = jnp.zeros(x.shape, dt)
+        for blk in dg.buckets:
+            rmax = jnp.max(jnp.take(x, blk.idx, axis=0)
+                           * blk.mask.astype(dt), axis=1, initial=0)
+            out = out.at[blk.rows].max(rmax, mode="drop")
+        tile_max = jnp.max(jnp.take(x, dg.hi_tiles, axis=0)
+                           * dg.hi_tmask.astype(dt), axis=1, initial=0)
+        hi_per_slot = jax.ops.segment_max(tile_max, dg.hi_rowmap,
+                                          num_segments=dg.n_hi_cap)
+        # empty segments -> -inf guard
+        hi_per_slot = jnp.maximum(hi_per_slot, 0)
+        return out.at[dg.hi_ids].max(hi_per_slot, mode="drop")
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["teleport", "rank_value", "relative_change", "rank_step"]
@@ -77,18 +78,23 @@ def rank_step(s: jnp.ndarray, r: jnp.ndarray, affected: jnp.ndarray,
     full [n] vector or on one shard's [n_loc] slice (pass the shard's
     affected mask already AND-ed with its validity mask, and the global
     vertex count as `n_norm`); `linf_delta` is then the *local* norm and the
-    caller owns the cross-device `pmax`.
+    caller owns the cross-device `pmax`. Its device ops carry the stage
+    names ``pr.update`` (the epilogue) and ``pr.converge`` (the L∞).
     """
-    dt = r.dtype
-    d = out_deg.astype(dt)
-    rv = rank_value(s, r, d, alpha=alpha,
-                    c0=teleport(alpha, n_norm, dt), closed_form=closed_form)
-    r_new = jnp.where(affected, rv, r)
-    dr, rel = relative_change(r_new, r)
-    if prune:
-        affected = affected & ~(rel <= tau_p)
-    if track_frontier:
-        delta_n = rel > tau_f
-    else:
-        delta_n = jnp.zeros(r.shape, dtype=jnp.bool_)
-    return r_new, affected, delta_n, jnp.max(dr)
+    with jax.named_scope("pr.update"):
+        dt = r.dtype
+        d = out_deg.astype(dt)
+        rv = rank_value(s, r, d, alpha=alpha,
+                        c0=teleport(alpha, n_norm, dt),
+                        closed_form=closed_form)
+        r_new = jnp.where(affected, rv, r)
+        dr, rel = relative_change(r_new, r)
+        if prune:
+            affected = affected & ~(rel <= tau_p)
+        if track_frontier:
+            delta_n = rel > tau_f
+        else:
+            delta_n = jnp.zeros(r.shape, dtype=jnp.bool_)
+    with jax.named_scope("pr.converge"):
+        linf = jnp.max(dr)
+    return r_new, affected, delta_n, linf

@@ -8,8 +8,10 @@ rebuild fallbacks, scatter traffic. A ``Registry`` aggregates
     as ``with registry.span("snapshot.device_refresh"): ...``. Spans may
     additionally emit a ``jax.profiler.TraceAnnotation`` (``annotate=True``)
     so the same names appear on the device timeline when a profiler trace
-    is being captured — the hook the tentpole asks for around kernel
-    dispatch; it is a no-op overhead-wise when no trace is active.
+    is being captured; keyword arguments (``span(name, annotate=True,
+    seq=3)``) and the registry's current ``tagged(...)`` values ride along
+    as the annotation's stats, so a trace viewer groups one batch's spans.
+    It is a no-op overhead-wise when no trace is active.
   * **counters** — monotonic ``inc(name, v)`` accumulators (in-place edits
     vs rebuild fallbacks, rows/tiles scattered, migrations, per-engine
     batch counts...).
@@ -68,6 +70,8 @@ class Registry:
 
     def __init__(self):
         self._lock = threading.Lock()
+        #: per-thread annotation stats set by `tagged` (e.g. a batch's seq)
+        self._local = threading.local()
         self._spans: Dict[str, SpanStats] = {}
         self._counters: Dict[str, int] = {}
         #: per-span latency histograms (obs.hist) — the p50/p95/p99 source;
@@ -87,10 +91,22 @@ class Registry:
     # -- spans ---------------------------------------------------------------
 
     @contextmanager
-    def span(self, name: str, annotate: bool = False):
-        ann = (_TraceAnnotation(name) if annotate and
-               _TraceAnnotation is not None else None)
-        if ann is not None:
+    def tagged(self, **tags):
+        """Add ``tags`` to every annotated span this thread opens inside the
+        block (nested blocks merge, the inner value winning)."""
+        outer = getattr(self._local, "tags", {})
+        self._local.tags = {**outer, **tags}
+        try:
+            yield
+        finally:
+            self._local.tags = outer
+
+    @contextmanager
+    def span(self, name: str, annotate: bool = False, **stats):
+        ann = None
+        if annotate and _TraceAnnotation is not None:
+            ann = _TraceAnnotation(
+                name, **{**getattr(self._local, "tags", {}), **stats})
             ann.__enter__()
         t0 = time.perf_counter()
         try:

@@ -2,7 +2,8 @@
 
 Feeds `temporal_stream` / `random_batch` workloads batch-by-batch through a
 session, recording per-batch latency split into the lifecycle stages
-(ingest / snapshot host / snapshot device / DF-P solve) plus optional
+(ingest / snapshot host / DF-P solve, which also waits out the snapshot's
+device scatters) plus optional
 ground-truth error against a from-scratch static recompute — the paper's
 §5.1.4 measurement protocol as a reusable harness.
 """

@@ -117,8 +117,7 @@ class BatchStats:
 
     @property
     def total_s(self) -> float:
-        return (self.ingest_s + self.snapshot.host_s
-                + self.snapshot.device_s + self.solve_s)
+        return self.ingest_s + self.snapshot.host_s + self.solve_s
 
 
 def _caps_to_json(caps: Optional[FrontierCaps]):
@@ -235,11 +234,16 @@ class StreamSession:
 
     def apply(self, batch: BatchUpdate | Delta) -> jnp.ndarray:
         """Apply Δ^t and return the new rank vector (device-resident;
-        stacked [nd, n_loc] in mesh mode — see `flat_ranks`)."""
+        stacked [nd, n_loc] in mesh mode — see `flat_ranks`). Every
+        annotated span of the batch carries its sequence number ``seq``."""
+        with _obs().tagged(seq=self._batch_idx + 1):
+            return self._apply(batch)
+
+    def _apply(self, batch: BatchUpdate | Delta) -> jnp.ndarray:
         obs = _obs()
         flight = get_flight()
         t0 = time.perf_counter()
-        with obs.span("session.ingest"):
+        with obs.span("session.ingest", annotate=True):
             quarantined = 0
             if isinstance(batch, Delta):
                 delta = batch
@@ -278,12 +282,13 @@ class StreamSession:
         snap_stats = self.snap.apply(delta)
 
         t1 = time.perf_counter()
-        engine = self._choose_engine(delta)
-        obs.inc(f"session.engine.{engine}")
-        flight.emit("session.engine", seq=seq, engine=engine,
-                    size=delta.size)
-        caps = self._frontier_caps(frontier_estimate(delta,
-                                                     self.snap._outdeg))
+        with obs.span("session.plan", annotate=True):
+            engine = self._choose_engine(delta)
+            obs.inc(f"session.engine.{engine}")
+            flight.emit("session.engine", seq=seq, engine=engine,
+                        size=delta.size)
+            caps = self._frontier_caps(frontier_estimate(delta,
+                                                         self.snap._outdeg))
         guarded = self.guard is not None
         r_pre = self.ranks
         self._maybe_capture_start()
@@ -726,12 +731,16 @@ class StreamSession:
         verification anchor); resets the session's rank state. Appends an
         ``engine="recompute"`` record to ``history`` and bumps the
         ``session.recompute`` counter, so resyncs are visible in the same
-        accounting stream as regular batches."""
-        t0 = time.perf_counter()
-        self.ranks, iters = self._static_solve()
-        _obs().inc("session.recompute")
+        accounting stream as regular batches. Its ``solve_s`` ends when the
+        ranks are ready, inside the annotated ``session.recompute`` span."""
+        obs = _obs()
+        with obs.span("session.recompute", annotate=True):
+            t0 = time.perf_counter()
+            self.ranks, iters = self._static_solve()
+            self.ranks = jax.block_until_ready(self.ranks)
+            solve_s = time.perf_counter() - t0
+        obs.inc("session.recompute")
         self.history.append(BatchStats(
             batch_size=0, engine="recompute", iters=int(iters),
-            ingest_s=0.0, snapshot=SnapshotStats(),
-            solve_s=time.perf_counter() - t0))
+            ingest_s=0.0, snapshot=SnapshotStats(), solve_s=solve_s))
         return self.ranks

@@ -70,7 +70,6 @@ class SnapshotStats:
     rebuilt: bool = False
     rebuild_reason: str = ""
     host_s: float = 0.0
-    device_s: float = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -79,12 +78,14 @@ class SnapshotStats:
 
 @jax.jit
 def _scatter_pair(idx, mask, rows, new_idx, new_mask):
-    return idx.at[rows].set(new_idx), mask.at[rows].set(new_mask)
+    with jax.named_scope("snapshot.scatter"):
+        return idx.at[rows].set(new_idx), mask.at[rows].set(new_mask)
 
 
 @jax.jit
 def _scatter_1d(dst, idx, vals):
-    return dst.at[idx].set(vals)
+    with jax.named_scope("snapshot.scatter"):
+        return dst.at[idx].set(vals)
 
 
 def _pad_rows(rows: np.ndarray, cap: int) -> np.ndarray:
@@ -534,7 +535,9 @@ class _HalfLayout:
         rows = jnp.asarray(rows)
         if self.scatter_impl == "pallas":
             from ..kernels.stream_scatter import ell_scatter_rows
-            return ell_scatter_rows(dev_idx, dev_mask, rows, new_i, new_m)
+            with jax.named_scope("snapshot.scatter"):
+                return ell_scatter_rows(dev_idx, dev_mask, rows, new_i,
+                                        new_m)
         return _scatter_pair(dev_idx, dev_mask, rows, new_i, new_m)
 
     def device_refresh(self) -> tuple:
@@ -731,7 +734,7 @@ class DeviceSnapshot:
         obs = _obs()
         t0 = time.perf_counter()
         stats = SnapshotStats()
-        with obs.span("snapshot.apply_net_delta"):
+        with obs.span("snapshot.apply_net_delta", annotate=True):
             self._keys, (d_s, d_d), (i_s, i_d) = apply_net_delta(
                 self._keys, self.n, delta, self._indeg, self._outdeg)
         stats.net_del, stats.net_ins = int(d_s.size), int(i_s.size)
@@ -750,7 +753,7 @@ class DeviceSnapshot:
 
         mig0 = self._pull.migrations + self._fwd.migrations
         try:
-            with obs.span("snapshot.host_edit"):
+            with obs.span("snapshot.host_edit", annotate=True):
                 for u, v in zip(d_s.tolist(), d_d.tolist()):
                     self._pull.delete(v, u)
                     self._fwd.delete(u, v)
@@ -770,7 +773,8 @@ class DeviceSnapshot:
 
         stats.migrations = self._pull.migrations + self._fwd.migrations - mig0
         stats.host_s = time.perf_counter() - t0
-        t1 = time.perf_counter()
+        # enqueues the scatters; their device time is the trace's
+        # ``snapshot.scatter`` stage
         with obs.span("snapshot.device_refresh", annotate=True):
             rows_p, tiles_p = self._pull.device_refresh()
             rows_f, tiles_f = self._fwd.device_refresh()
@@ -787,7 +791,6 @@ class DeviceSnapshot:
                     jnp.asarray(self._indeg[at].astype(np.int32)))
         stats.rows_touched = rows_p + rows_f
         stats.tiles_touched = tiles_p + tiles_f
-        stats.device_s = time.perf_counter() - t1
         obs.inc("snapshot.inplace_batches")
         obs.inc("snapshot.rows_touched", stats.rows_touched)
         obs.inc("snapshot.tiles_touched", stats.tiles_touched)

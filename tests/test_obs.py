@@ -411,3 +411,179 @@ def test_seed_report_is_valid():
               if b.get("trace")}
     assert any(n.startswith("static/") for n in traces)
     assert any("dfp" in n for n in traces)
+
+
+# -- stage names on the device (jax.named_scope) -------------------------------
+
+def _stage_programs():
+    """(name, lower thunk, scopes) for each program whose stages are named:
+    the static sweep, the dense DF-P engine with frontier caps, the compact
+    loop and its dense finish, and the snapshot's row scatter."""
+    from repro.core.compact import _compact_loop, _dense_finish
+    from repro.core.dynamic import _dfp_pagerank
+    from repro.core.frontier import caps_for, initial_affected
+    from repro.core.pagerank import PRParams, _static_pagerank
+    from repro.stream.snapshot import _scatter_pair
+
+    g0 = powerlaw_graph(300, 3000, seed=2)
+    b = random_batch(g0, 0.01, seed=5)
+    g = apply_batch(g0, b)
+    dg = device_graph(g, d_p=16, tile=64)
+    fwd = forward_device_graph(g, d_p=16, tile=64)
+    db = batch_to_device(b, g.n)
+    r = init_ranks(g.n)
+    p = PRParams()
+    dv, dn = initial_affected(g.n, db.del_src, db.del_dst, db.ins_src)
+    caps = caps_for(dg, 64)
+    kt = int(dg.hi_tiles.shape[0])
+    solve = {"pr.pull", "pr.update", "pr.converge"}
+    frontier = solve | {"pr.compact", "pr.expand"}
+    idx = jnp.zeros((64, 8), jnp.int32)
+    rows = jnp.arange(4, dtype=jnp.int32)
+    return [
+        ("static", lambda: _static_pagerank.lower(dg, r, p), solve),
+        ("dfp_caps", lambda: _dfp_pagerank.lower(
+            dg, fwd, r, db, p, None, False, caps, True), frontier),
+        ("compact_loop", lambda: _compact_loop.lower(
+            dg, fwd, r, dv, dn, p, 64, kt, 64, True), frontier),
+        ("dense_finish", lambda: _dense_finish.lower(
+            dg, r, dv, dn, p, True, None, jnp.asarray(0, jnp.int32), True),
+         solve | {"pr.expand"}),
+        ("scatter_pair", lambda: _scatter_pair.lower(
+            idx, idx.astype(jnp.float32), rows, idx[:4] + 1,
+            idx[:4].astype(jnp.float32)), {"snapshot.scatter"}),
+    ]
+
+
+_STAGE_CASES = ["static", "dfp_caps", "compact_loop", "dense_finish",
+                "scatter_pair"]
+
+
+def _stage_case(name):
+    return {c[0]: c for c in _stage_programs()}[name]
+
+
+@pytest.mark.parametrize("name", _STAGE_CASES)
+def test_stage_scopes_in_lowered_program(name):
+    import re
+    _, lower, scopes = _stage_case(name)
+    text = lower().as_text(debug_info=True)
+    named = set(re.findall(r"(?:pr|snapshot)\.[a-z]+", text))
+    assert scopes <= named, (scopes - named)
+
+
+def _compiled_body(compiled):
+    """The compiled HLO without op metadata and without the trailing
+    source-location tables: the program as the device runs it."""
+    import re
+    tables = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+    blocks = [blk for blk in compiled.as_text().split("\n\n")
+              if blk.strip().split("\n")[0] not in tables]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+
+
+@pytest.mark.parametrize("name", _STAGE_CASES)
+def test_stage_scopes_leave_compiled_program_unchanged(name, monkeypatch):
+    import contextlib
+    import re
+    _, lower, _ = _stage_case(name)
+    body = _compiled_body(lower().compile())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    jax.clear_caches()
+    try:
+        plain = lower()
+        assert "pr." not in plain.as_text(debug_info=True)
+        bare = _compiled_body(plain.compile())
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    op = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ", re.M)
+    assert len(op.findall(body)) == len(op.findall(bare)) > 0
+    assert body == bare
+
+
+# -- host spans and compact counters -------------------------------------------
+
+def test_span_stats_reach_the_annotation(monkeypatch):
+    import repro.obs.spans as spans_mod
+    seen = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(spans_mod, "_TraceAnnotation", Ann)
+    reg = Registry()
+    with reg.tagged(seq=3):
+        with reg.span("a", annotate=True):
+            pass
+        with reg.tagged(seq=4, part=1):
+            with reg.span("b", annotate=True, k=2):
+                pass
+        with reg.span("c"):
+            pass
+    with reg.span("d", annotate=True):
+        pass
+    assert seen == [("a", {"seq": 3}), ("b", {"seq": 4, "part": 1, "k": 2}),
+                    ("d", {})]
+    assert reg.span_stats("c").count == 1
+
+
+def test_compact_counters_count_the_overflow():
+    from repro.core.compact import _df_like_compact
+    from repro.core.pagerank import PRParams
+    g0 = powerlaw_graph(2000, 20000, seed=3)
+    b = random_batch(g0, 0.001, seed=5)
+    g = apply_batch(g0, b)
+    dg = device_graph(g, d_p=16, tile=64)
+    fwd = forward_device_graph(g, d_p=16, tile=64)
+    r_prev, _ = static_pagerank(device_graph(g0, d_p=16, tile=64),
+                                init_ranks(g0.n))
+    reset_registry()
+    reg = get_registry()
+    # headroom 1: the capacity is the initial frontier, which expansion
+    # outgrows in the first sweeps
+    r, iters = _df_like_compact(dg, fwd, r_prev, batch_to_device(b, g.n),
+                                PRParams(), prune=True, headroom=1)
+    assert reg.counter("compact.batches") == 1
+    assert reg.counter("compact.overflows") == 1
+    assert reg.counter("compact.sweeps") < int(iters)
+    assert reg.counter("compact.capacity") >= 16
+    assert reg.span_stats("compact.finish").count == 1
+    reset_registry()
+
+
+def test_apply_records_the_new_spans():
+    from repro.stream import StreamSession
+    from repro.core import BatchUpdate
+    g = powerlaw_graph(500, 4000, seed=4)
+    sess = StreamSession(g, d_p=16, tile=64, engine="compact")
+    reset_registry()
+    rng = np.random.default_rng(2)
+    s = rng.integers(0, 500, 10).astype(np.int32)
+    d = rng.integers(0, 500, 10).astype(np.int32)
+    ok = s != d
+    sess.apply(BatchUpdate(del_src=np.zeros(0, np.int32),
+                           del_dst=np.zeros(0, np.int32),
+                           ins_src=s[ok], ins_dst=d[ok]))
+    sess.recompute()
+    rep = get_registry().report()
+    for name in ("session.ingest", "snapshot.apply_net_delta",
+                 "snapshot.host_edit", "snapshot.device_refresh",
+                 "session.plan", "session.solve", "solve.dfp_compact",
+                 "compact.plan", "compact.check", "session.recompute",
+                 "solve.static"):
+        assert rep["spans"][name]["count"] == 1, name
+    assert rep["counters"]["compact.batches"] == 1
+    assert rep["counters"]["compact.sweeps"] <= sess.history[0].iters
+    st = sess.history[-1]
+    assert st.engine == "recompute" and st.solve_s > 0
+    assert not hasattr(sess.history[0].snapshot, "device_s")
+    reset_registry()
