@@ -31,8 +31,18 @@ by row/tile scatters, via `kernels.stream_scatter` on TPU):
 Fallback: capacity exhaustion (slot/tile free list empty), fragmentation
 above budget, or a batch too large for incremental maintenance to win
 (`rebuild_threshold` · |E|) all route to a full vectorized `build_hybrid`
-rebuild at fixed capacities (grown by pow2 when genuinely exceeded, which
-is the only event that changes device shapes / retriggers jit).
+rebuild at fixed capacities (grown by pow2 when genuinely exceeded).
+
+Capacity and device extent differ. The host mirrors hold each part's
+*capacity*, the hysteresis band times a headroom, so that placements rarely
+exhaust it. The device holds only each part's first `extent` rows: the rows
+in use plus a margin, rounded up on a ladder of 16 steps an octave. A sweep
+gathers every row the device holds, whatever it points at, so sweeping the
+reserve would cost as much as sweeping edges. Free slots are handed out
+lowest first, so the rows in use stay at the front. A placement that
+reaches a part's extent steps it up the ladder and re-stages the half from
+its mirrors; extents never shrink. Extent steps and rebuilds are the
+only events that change device shapes / retrigger jit.
 """
 from __future__ import annotations
 
@@ -44,9 +54,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.graph import (Graph, HybridLayout, bucket_band_counts,
-                          build_hybrid, choose_bucket_widths, edge_keys,
-                          graph_from_sorted_keys, keys_to_edges)
+from ..core.graph import (EllBucket, Graph, HybridLayout, HybridRows,
+                          bucket_band_counts, build_hybrid,
+                          choose_bucket_widths, edge_keys,
+                          graph_from_sorted_keys, keys_to_edges,
+                          layout_slot_stats)
 from ..core.pagerank import DeviceGraph, EllBlock
 from ..obs.flight import get_flight
 from ..obs.spans import get_registry as _obs
@@ -92,6 +104,29 @@ def _pad_rows(rows: np.ndarray, cap: int) -> np.ndarray:
     out = np.full(cap, rows[0], np.int32)
     out[:rows.size] = rows
     return out
+
+
+def _ladder(x: int) -> int:
+    """Round `x` up to the device-extent ladder: 16 steps an octave, each a
+    multiple of 8."""
+    step = max(8, 1 << max(x.bit_length() - 5, 0))
+    return -(-x // step) * step
+
+
+def _fit_extent(need: int, extent: int, limit: int) -> int:
+    """The device extent of a part whose first `need` rows must be on the
+    device: `extent` where it covers them (extents never shrink), else
+    `need` plus a 1/32 margin, rounded up the ladder. Never past `limit`;
+    an extent of 0 (none yet) is always fitted."""
+    need = min(need, limit)
+    if 0 < extent and need <= extent:
+        return extent
+    return min(limit, _ladder(need + max(8, need >> 5)))
+
+
+def _last_used(used: np.ndarray) -> int:
+    at = np.flatnonzero(used)
+    return int(at[-1]) + 1 if at.size else 0
 
 
 def apply_net_delta(keys: np.ndarray, n: int, delta: Delta,
@@ -159,7 +194,8 @@ class _HalfLayout:
     """
 
     def __init__(self, lay, row_deg: np.ndarray,
-                 scatter_impl: str = "jnp", stage_device: bool = True):
+                 scatter_impl: str = "jnp", stage_device: bool = True,
+                 extents: Optional[List[int]] = None):
         n = lay.n
         self.n, self.d_p, self.tile = n, lay.d_p, lay.tile
         self.widths = tuple(lay.widths)
@@ -214,20 +250,74 @@ class _HalfLayout:
         # (stream/sharded.py) reuses this host-edit machinery per shard but
         # owns STACKED device arrays itself, draining `drain_dirty()` into
         # per-shard scatters instead of calling `device_refresh`.
+        # Device extents, one per part: each bucket, then the hi-slot table,
+        # then the tile pool (see the module docstring). `extents` carries
+        # a previous layout's extents across a rebuild or a restore.
         self._staged = stage_device
         if stage_device:
+            self.extents = list(extents) if extents else [0] * (nb + 2)
+            self._fit_extents(self._in_use())
             self._stage_device()
 
+    # -- device extents -------------------------------------------------------
+
+    def _limits(self) -> List[int]:
+        """Each part's largest extent: its capacity, and |V| for a bucket."""
+        return ([min(r.shape[0], self.n) for r in self.bk_rows]
+                + [self.hi_ids.shape[0], self.hi_tiles.shape[0]])
+
+    def _in_use(self) -> List[int]:
+        """Rows each part needs on the device, from a scan of the mirrors:
+        one past its last used row. The hi-slot table needs one row more, a
+        free slot that the device's pad tiles point at."""
+        return ([_last_used(r < self.n) for r in self.bk_rows]
+                + [_last_used(self.hi_ids < self.n) + 1,
+                   _last_used(self.hi_tmask.any(axis=1))])
+
+    def _fit_extents(self, need: List[int]) -> List[int]:
+        """Step every part whose `need` passed its extent; returns the
+        indices of the parts that stepped."""
+        grown = []
+        for p, (nd, lim) in enumerate(zip(need, self._limits())):
+            e = _fit_extent(nd, self.extents[p], lim)
+            if e != self.extents[p]:
+                self.extents[p] = e
+                grown.append(p)
+        return grown
+
+    def device_rows(self) -> HybridRows:
+        """The layout the device holds: views of the mirrors cut to the
+        extents. Pad tiles point at the hi table's last device row, a free
+        slot, where the mirror points them at its last host row."""
+        nb = len(self.widths)
+        eh, et = self.extents[nb], self.extents[nb + 1]
+        return HybridRows(
+            d_p=self.d_p, tile=self.tile, widths=self.widths,
+            buckets=tuple(
+                EllBucket(width=w, rows=self.bk_rows[bi][:e],
+                          idx=self.bk_idx[bi][:e], mask=self.bk_mask[bi][:e])
+                for bi, (w, e) in enumerate(zip(self.widths, self.extents))),
+            bucket_of=self.bucket_of, slot_of=self.slot_of,
+            hi_ids=self.hi_ids[:eh], hi_tiles=self.hi_tiles[:et],
+            hi_tmask=self.hi_tmask[:et],
+            hi_rowmap=np.minimum(self.hi_rowmap[:et], np.int32(eh - 1)),
+            is_low=self.is_low, row_deg=self.row_deg)
+
+    def swept_slots(self) -> int:
+        """Slots one full pull over the device copy gathers."""
+        return layout_slot_stats(self.device_rows())["gathered_slots"]
+
     def _stage_device(self) -> None:
-        self.dev_bk_rows = [jnp.asarray(a.copy()) for a in self.bk_rows]
-        self.dev_bk_idx = [jnp.asarray(a.copy()) for a in self.bk_idx]
-        self.dev_bk_mask = [jnp.asarray(a.copy()) for a in self.bk_mask]
+        d = self.device_rows()
+        self.dev_bk_rows = [jnp.asarray(b.rows.copy()) for b in d.buckets]
+        self.dev_bk_idx = [jnp.asarray(b.idx.copy()) for b in d.buckets]
+        self.dev_bk_mask = [jnp.asarray(b.mask.copy()) for b in d.buckets]
         self.dev_bucket_of = jnp.asarray(self.bucket_of.copy())
         self.dev_slot_of = jnp.asarray(self.slot_of.copy())
-        self.dev_hi_tiles = jnp.asarray(self.hi_tiles.copy())
-        self.dev_hi_tmask = jnp.asarray(self.hi_tmask.copy())
-        self.dev_hi_rowmap = jnp.asarray(self.hi_rowmap.copy())
-        self.dev_hi_ids = jnp.asarray(self.hi_ids.copy())
+        self.dev_hi_tiles = jnp.asarray(d.hi_tiles.copy())
+        self.dev_hi_tmask = jnp.asarray(d.hi_tmask.copy())
+        self.dev_hi_rowmap = jnp.asarray(d.hi_rowmap)
+        self.dev_hi_ids = jnp.asarray(d.hi_ids.copy())
         self.dev_is_low = jnp.asarray(self.is_low.copy())
 
     # -- checkpoint state (guard.journal) ------------------------------------
@@ -271,7 +361,8 @@ class _HalfLayout:
 
     def load_state(self, st: dict, prefix: str) -> None:
         """Inverse of ``state_dict`` — overwrites the mirrors of a half
-        built at the SAME capacities, then restages the device arrays."""
+        built at the SAME capacities, then restages the device arrays (at
+        the half's extents, stepped where the mirrors need more)."""
         nb = len(self.widths)
         for bi in range(nb):
             self.bk_rows[bi] = np.ascontiguousarray(st[f"{prefix}bk_rows{bi}"])
@@ -295,6 +386,7 @@ class _HalfLayout:
         self._bmap_dirty = [False] * nb
         self._rowmap_dirty = self._side_dirty = False
         if self._staged:
+            self._fit_extents(self._in_use())
             self._stage_device()
 
     # -- dirty-state handoff (sharded snapshot path) -------------------------
@@ -541,9 +633,35 @@ class _HalfLayout:
         return _scatter_pair(dev_idx, dev_mask, rows, new_i, new_m)
 
     def device_refresh(self) -> tuple:
-        """Push dirty slots/tiles to the device arrays; returns (#slots, #tiles)."""
+        """Push dirty slots/tiles to the device arrays; returns (#slots, #tiles).
+
+        Where placements reached a part's extent, the part steps up the
+        ladder (counted in ``snapshot.extent_grows``) and the half is
+        re-staged whole from its mirrors, a device upload; otherwise the
+        dirty rows are scattered, all of them below their part's extent."""
         nr = sum(len(s) for s in self._dirty_slots)
         nt = len(self._dirty_tiles)
+        # dirty ids cover every placement since the last refresh; hi slots
+        # are placed only where `_side_dirty` is set
+        need = [max(d) + 1 if d else 0 for d in self._dirty_slots]
+        need.append(_last_used(self.hi_ids < self.n) + 1
+                    if self._side_dirty else 0)
+        need.append(max(self._dirty_tiles) + 1 if nt else 0)
+        grown = self._fit_extents(need)
+        if grown:
+            _obs().inc("snapshot.extent_grows", len(grown))
+            self._stage_device()
+        else:
+            self._scatter_dirty()
+        for s in self._dirty_slots:
+            s.clear()
+        self._dirty_tiles.clear()
+        self._bmap_dirty = [False] * len(self.widths)
+        self._rowmap_dirty = self._side_dirty = False
+        return nr, nt
+
+    def _scatter_dirty(self) -> None:
+        d = self.device_rows()
         for bi, dirty in enumerate(self._dirty_slots):
             if dirty:
                 ids = np.fromiter(dirty, np.int32, len(dirty))
@@ -551,28 +669,22 @@ class _HalfLayout:
                     self.dev_bk_idx[bi], self.dev_bk_mask[bi],
                     self.bk_idx[bi], self.bk_mask[bi], ids)
             if self._bmap_dirty[bi]:
-                self.dev_bk_rows[bi] = jnp.asarray(self.bk_rows[bi].copy())
-        if nt:
-            ids = np.fromiter(self._dirty_tiles, np.int32, nt)
+                self.dev_bk_rows[bi] = jnp.asarray(d.buckets[bi].rows.copy())
+        if self._dirty_tiles:
+            ids = np.fromiter(self._dirty_tiles, np.int32,
+                              len(self._dirty_tiles))
             self.dev_hi_tiles, self.dev_hi_tmask = self._scatter(
                 self.dev_hi_tiles, self.dev_hi_tmask,
                 self.hi_tiles, self.hi_tmask, ids)
         # small 1-D side tables: re-staged wholesale, but only when touched
         # (.copy(): see the aliasing note in __init__)
         if self._rowmap_dirty:
-            self.dev_hi_rowmap = jnp.asarray(self.hi_rowmap.copy())
-            self._rowmap_dirty = False
+            self.dev_hi_rowmap = jnp.asarray(d.hi_rowmap)
         if self._side_dirty:
-            self.dev_hi_ids = jnp.asarray(self.hi_ids.copy())
+            self.dev_hi_ids = jnp.asarray(d.hi_ids.copy())
             self.dev_is_low = jnp.asarray(self.is_low.copy())
             self.dev_bucket_of = jnp.asarray(self.bucket_of.copy())
             self.dev_slot_of = jnp.asarray(self.slot_of.copy())
-            self._side_dirty = False
-        for s in self._dirty_slots:
-            s.clear()
-        self._dirty_tiles.clear()
-        self._bmap_dirty = [False] * len(self.widths)
-        return nr, nt
 
     def device_graph(self, out_deg: jnp.ndarray) -> DeviceGraph:
         buckets = tuple(
@@ -609,6 +721,7 @@ class DeviceSnapshot:
         self._keys = np.sort(edge_keys(g.n, src, dst))
         self._indeg = g.in_degree().astype(np.int64)
         self._outdeg = g.out_degree().astype(np.int64)
+        self._swept = 0
         self._adopt(g)
 
     # -- construction / rebuild ---------------------------------------------
@@ -641,20 +754,36 @@ class DeviceSnapshot:
         return dict(n_hi_cap=n_hi_cap, t_cap=t_cap,
                     widths=tuple(widths), bucket_caps=bucket_caps)
 
-    def _adopt(self, g: Graph, caps: Optional[dict] = None) -> None:
-        """(Re)build both halves from a host Graph at fixed capacities."""
+    def _adopt(self, g: Graph, caps: Optional[dict] = None,
+               extents: Optional[dict] = None) -> None:
+        """(Re)build both halves from a host Graph at fixed capacities,
+        at no smaller device extents than `extents` ({"p": .., "f": ..})."""
         caps = caps or self._caps_for(self._indeg, self._outdeg)
+        extents = extents or {}
         lay_p = build_hybrid(g, d_p=self.d_p, tile=self.tile, **caps)
         lay_f = build_hybrid(g.transpose(), d_p=self.d_p, tile=self.tile,
                              **caps)
         self._caps = caps
-        self._pull = _HalfLayout(lay_p, self._indeg, self._scatter_impl)
-        self._fwd = _HalfLayout(lay_f, self._outdeg, self._scatter_impl)
+        self._pull = _HalfLayout(lay_p, self._indeg, self._scatter_impl,
+                                 extents=extents.get("p"))
+        self._fwd = _HalfLayout(lay_f, self._outdeg, self._scatter_impl,
+                                extents=extents.get("f"))
         if self._low_water is not None:
             self._pull.low_water = self._low_water
             self._fwd.low_water = self._low_water
         self._dev_outdeg = jnp.asarray(self._outdeg.astype(np.int32))
         self._dev_indeg = jnp.asarray(self._indeg.astype(np.int32))
+        self._note_swept()
+
+    def _extents(self) -> dict:
+        return {"p": list(self._pull.extents), "f": list(self._fwd.extents)}
+
+    def _note_swept(self) -> None:
+        """Keep the counter ``snapshot.swept_slots`` at the slots one full
+        pull gathers: it moves by the change at each staging and step."""
+        now = self._pull.swept_slots()
+        _obs().inc("snapshot.swept_slots", now - self._swept)
+        self._swept = now
 
     def _rebuild(self, reason: str) -> None:
         g = self.graph()
@@ -670,7 +799,7 @@ class DeviceSnapshot:
                               zip(caps["bucket_caps"],
                                   self._caps["bucket_caps"])),
         )
-        self._adopt(g, caps)
+        self._adopt(g, caps, self._extents())
         self._last_rebuild_reason = reason
 
     # -- queries -------------------------------------------------------------
@@ -701,26 +830,30 @@ class DeviceSnapshot:
         session checkpoint. ``arrays`` is a flat {name: np.ndarray} dict
         (edge keys, degrees, both halves' mirrors + free-list orders);
         ``extra`` is the JSON-safe capacity signature ``load_state`` rebuilds
-        at (shapes must match for the mirror overwrite)."""
+        at (shapes must match for the mirror overwrite), with the device
+        extents."""
         arrays = dict(keys=self._keys, indeg=self._indeg,
                       outdeg=self._outdeg)
         arrays.update(self._pull.state_dict("p."))
         arrays.update(self._fwd.state_dict("f."))
         extra = {"caps": {k: list(v) if isinstance(v, tuple) else int(v)
-                          for k, v in self._caps.items()}}
+                          for k, v in self._caps.items()},
+                 "extents": self._extents()}
         return arrays, extra
 
     def load_state(self, arrays: dict, extra: dict) -> None:
         """Restore from ``state_dict`` output: re-adopt at the checkpointed
-        capacities (device shapes match), then overwrite every mirror."""
+        capacities and extents (device shapes match), then overwrite every
+        mirror."""
         self._keys = np.ascontiguousarray(arrays["keys"])
         self._indeg = np.ascontiguousarray(arrays["indeg"])
         self._outdeg = np.ascontiguousarray(arrays["outdeg"])
         caps = {k: tuple(v) if isinstance(v, list) else int(v)
                 for k, v in extra["caps"].items()}
-        self._adopt(self.graph(), caps)
+        self._adopt(self.graph(), caps, extra.get("extents"))
         self._pull.load_state(arrays, "p.")
         self._fwd.load_state(arrays, "f.")
+        self._note_swept()
 
     # -- the batch-update lifecycle ------------------------------------------
 
@@ -776,8 +909,11 @@ class DeviceSnapshot:
         # enqueues the scatters; their device time is the trace's
         # ``snapshot.scatter`` stage
         with obs.span("snapshot.device_refresh", annotate=True):
+            pull_extents = list(self._pull.extents)
             rows_p, tiles_p = self._pull.device_refresh()
             rows_f, tiles_f = self._fwd.device_refresh()
+            if self._pull.extents != pull_extents:
+                self._note_swept()
             touched = np.unique(np.concatenate([d_s, d_d, i_s, i_d]))
             if touched.size:
                 at = _pad_rows(touched.astype(np.int32),

@@ -15,8 +15,8 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.core.graph import (BatchUpdate, apply_batch, random_batch,
-                              random_graph, temporal_stream)
+from repro.core.graph import (BatchUpdate, apply_batch, build_graph,
+                              random_batch, random_graph, temporal_stream)
 from repro.core.dynamic import dfp_pagerank
 from repro.core.compact import dfp_pagerank_compact
 from repro.core.pagerank import (PRParams, device_graph, init_ranks,
@@ -385,6 +385,38 @@ def test_restore_bit_identical(tmp_path, g):
     assert get_registry().counter("guard.restores") == 1
     # and the restored session keeps streaming identically
     b = random_batch(sess.snap.graph(), 16, seed=99)
+    r1, r2 = sess.apply(b), restored.apply(b)
+    assert np.array_equal(np.asarray(r1), np.asarray(r2))
+
+
+@pytest.mark.parametrize("scatter_impl", ["jnp", "pallas"])
+def test_restore_bit_identical_after_extent_step(tmp_path, scatter_impl):
+    """The same kill-and-restore after a device extent stepped: the
+    checkpoint restores those extents, not the smaller ones a fresh build
+    of its graph gets, and the ranks stay bit-identical."""
+    n = 600
+    v = np.arange(n, dtype=np.int32)
+    ring = build_graph(n, v, (v + 1) % n)     # every row in bucket 0
+    fan_in = BatchUpdate(del_src=np.zeros(0, np.int32),
+                         del_dst=np.zeros(0, np.int32),
+                         ins_src=np.full(20, 5, np.int32),
+                         ins_dst=np.arange(7, 27, dtype=np.int32))
+    d = str(tmp_path)
+    sess = StreamSession(ring, d_p=8, tile=32, journal_dir=d,
+                         checkpoint_every=2, scatter_impl=scatter_impl)
+    sess.apply(fan_in)              # 20 rows into bucket 1, extent 8
+    assert get_registry().counter("snapshot.extent_grows") == 1
+    for i in range(2):
+        sess.apply(random_batch(sess.snap.graph(), 16, seed=70 + i))
+    sess.close()
+
+    restored = StreamSession.restore(d)
+    stepped = sess.snap.state_dict()[1]["extents"]
+    assert restored.snap.state_dict()[1]["extents"] == stepped
+    fresh = DeviceSnapshot(sess.snap.graph(), d_p=8, tile=32)
+    assert fresh.state_dict()[1]["extents"] != stepped
+    assert np.array_equal(np.asarray(sess.ranks), np.asarray(restored.ranks))
+    b = random_batch(sess.snap.graph(), 16, seed=98)
     r1, r2 = sess.apply(b), restored.apply(b)
     assert np.array_equal(np.asarray(r1), np.asarray(r2))
 

@@ -6,11 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (BatchUpdate, apply_batch, build_graph,
-                        device_graph, dfp_pagerank, dfp_pagerank_compact,
-                        edge_keys, init_ranks, l1_error, powerlaw_graph,
-                        pull_sum, random_batch, random_graph, static_pagerank,
-                        temporal_stream)
+from repro.core import (BatchUpdate, DeviceGraph, EllBlock, apply_batch,
+                        build_graph, device_graph, dfp_pagerank,
+                        dfp_pagerank_compact, edge_keys, init_ranks,
+                        l1_error, powerlaw_graph, pull_sum, random_batch,
+                        random_graph, static_pagerank, temporal_stream,
+                        update_ranks)
+from repro.obs.spans import get_registry, reset_registry
 from repro.stream import (DeviceSnapshot, StreamSession, ingest, next_pow2,
                           replay, churn_workload)
 
@@ -218,6 +220,157 @@ def test_snapshot_pallas_scatter_matches_jnp():
         c = jnp.asarray(rng.random(g.n))
         np.testing.assert_array_equal(np.asarray(pull_sum(sp.dg, c)),
                                       np.asarray(pull_sum(sj.dg, c)))
+
+
+# ---------------------------------------------------------------------------
+# snapshot: device extents
+# ---------------------------------------------------------------------------
+
+def _in_use(half):
+    """Rows each part of a half holds (buckets, hi slots, tiles)."""
+    def last(used):
+        at = np.flatnonzero(used)
+        return int(at[-1]) + 1 if at.size else 0
+    return ([last(r < half.n) for r in half.bk_rows]
+            + [last(half.hi_ids < half.n), last(half.hi_tmask.any(axis=1))])
+
+
+def _assert_extents_fit(snap):
+    """Every device extent covers the rows in use and exceeds them by at
+    most a margin and a ladder step (1/32 and 1/16 of them, 8 rows at
+    least each); no bucket extent exceeds |V|; the counter holds the slots
+    one full pull gathers."""
+    for half in (snap._pull, snap._fwd):
+        nb = len(half.widths)
+        use = _in_use(half)
+        use[nb] += 1                # the hi table keeps one free slot
+        for p, (u, e) in enumerate(zip(use, half.extents)):
+            assert u <= e <= u + max(8, u // 32) + max(8, u // 16) + 8, p
+        assert all(e <= snap.n for e in half.extents[:nb])
+        dg = half.device_graph(snap._dev_outdeg)
+        assert [b.rows.shape[0] for b in dg.buckets] == half.extents[:nb]
+        assert dg.n_hi_cap == half.extents[nb]
+        assert dg.hi_tiles.shape[0] == half.extents[nb + 1]
+        # every tile points into the device's hi table; empty tiles at a
+        # free slot, which compaction reads as inactive
+        rowmap, ids = np.asarray(dg.hi_rowmap), np.asarray(dg.hi_ids)
+        assert rowmap.max() < ids.size
+        empty = ~np.asarray(dg.hi_tmask).any(axis=1)
+        assert np.all(ids[rowmap[empty]] == snap.n)
+    pull = snap._pull
+    assert get_registry().counter("snapshot.swept_slots") == (
+        sum(w * e for w, e in zip(pull.widths, pull.extents))
+        + pull.extents[-1] * pull.tile)
+
+
+def _full_reserve_dg(snap):
+    """The pull half as the device held it before extents: every mirror
+    row, up to the host capacity."""
+    h = snap._pull
+    return DeviceGraph(
+        buckets=tuple(EllBlock(rows=jnp.asarray(r), idx=jnp.asarray(i),
+                               mask=jnp.asarray(m))
+                      for r, i, m in zip(h.bk_rows, h.bk_idx, h.bk_mask)),
+        bucket_of=jnp.asarray(h.bucket_of), slot_of=jnp.asarray(h.slot_of),
+        hi_ids=jnp.asarray(h.hi_ids), hi_tiles=jnp.asarray(h.hi_tiles),
+        hi_tmask=jnp.asarray(h.hi_tmask), hi_rowmap=jnp.asarray(h.hi_rowmap),
+        is_low=jnp.asarray(h.is_low), out_deg=snap.dg.out_deg)
+
+
+def _assert_sweep_bit_identical(snap, r):
+    """`update_ranks` on the snapshot equals the same sweep on a fresh
+    build of its graph (contributions are whole numbers, so the sums are
+    exact in any order) and, for ranks `r`, on the full host reserve."""
+    kw = dict(alpha=0.85, tau_f=1e-9, tau_p=1e-9, prune=True,
+              closed_form=True, track_frontier=True)
+    n = snap.n
+    aff = jnp.asarray(np.arange(n) % 3 > 0)
+    whole = jnp.asarray(np.asarray(snap.dg.out_deg, np.float64)
+                        * (1 + np.arange(n) % 7))
+    fresh = device_graph(snap.graph(), d_p=snap.d_p, tile=snap.tile)
+    for got, want in zip(update_ranks(snap.dg, whole, aff, **kw),
+                         update_ranks(fresh, whole, aff, **kw)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for got, want in zip(update_ranks(snap.dg, r, aff, **kw),
+                         update_ranks(_full_reserve_dg(snap), r, aff, **kw)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("scatter_impl", ["jnp", "pallas"])
+def test_snapshot_device_extents_track_occupancy(scatter_impl):
+    reset_registry()
+    g = powerlaw_graph(600, 6000, seed=30)
+    snap = DeviceSnapshot(g, **CAPS, scatter_impl=scatter_impl)
+    _assert_extents_fit(snap)
+    pull = snap._pull
+    assert sum(w * e for w, e in zip(pull.widths, pull.extents)) < sum(
+        r.size for r in pull.bk_idx)           # the reserve stays on the host
+    gg = g
+    for t in range(4):
+        b = random_batch(gg, 0.01, seed=31 + t)
+        assert not snap.apply(ingest(b, g.n)).rebuilt
+        gg = apply_batch(gg, b)
+        _assert_extents_fit(snap)
+
+
+@pytest.mark.parametrize("scatter_impl", ["jnp", "pallas"])
+def test_snapshot_sweep_bit_identical_across_churn(scatter_impl):
+    g = powerlaw_graph(600, 6000, seed=32)
+    snap = DeviceSnapshot(g, **CAPS, scatter_impl=scatter_impl)
+    r = jnp.asarray(np.random.default_rng(33).random(g.n))
+    _assert_sweep_bit_identical(snap, r)
+    gg, migrations, deleted = g, 0, 0
+    for t in range(3):
+        b = random_batch(gg, 0.02, seed=34 + t)
+        st = snap.apply(ingest(b, g.n))
+        assert not st.rebuilt
+        migrations += st.migrations
+        deleted += st.net_del
+        gg = apply_batch(gg, b)
+        _assert_sweep_bit_identical(snap, r)
+    assert migrations > 0 and deleted > 0
+
+
+def _ring(n):
+    """Every vertex has one in- and one out-neighbour besides its
+    self-loop, so every row sits in the narrowest bucket."""
+    v = np.arange(n, dtype=np.int32)
+    return build_graph(n, v, (v + 1) % n)
+
+
+def _fan_in(n, src, k):
+    """k new in-edges from `src`, one onto each of k distinct vertices."""
+    dst = np.arange(src + 2, src + 2 + k, dtype=np.int32) % n
+    return BatchUpdate(del_src=np.zeros(0, np.int32),
+                       del_dst=np.zeros(0, np.int32),
+                       ins_src=np.full(k, src, np.int32), ins_dst=dst)
+
+
+@pytest.mark.parametrize("scatter_impl", ["jnp", "pallas"])
+def test_snapshot_extent_step_restages_one_part(scatter_impl):
+    """20 rows of the pull half migrate into an empty bucket whose extent
+    holds 8: that bucket, and no other part, steps, once."""
+    reset_registry()
+    n = 600
+    g = _ring(n)
+    snap = DeviceSnapshot(g, d_p=8, tile=32, scatter_impl=scatter_impl)
+    assert snap._pull.widths == (2, 8)
+    r = jnp.asarray(np.random.default_rng(35).random(n))
+    _assert_sweep_bit_identical(snap, r)
+    before = [list(h.extents) for h in (snap._pull, snap._fwd)]
+    st = snap.apply(ingest(_fan_in(n, 5, 20), n))
+    assert not st.rebuilt and st.migrations >= 20
+    assert get_registry().counter("snapshot.extent_grows") == 1
+    assert snap._pull.extents[1] > 20 >= before[0][1]
+    assert snap._pull.extents[0] == before[0][0]
+    assert snap._pull.extents[2:] == before[0][2:]
+    assert snap._fwd.extents == before[1]
+    _assert_extents_fit(snap)
+    _assert_sweep_bit_identical(snap, r)
+    # the next batch scatters into the stepped part without another step
+    snap.apply(ingest(_fan_in(n, 300, 3), n))
+    assert get_registry().counter("snapshot.extent_grows") == 1
+    _assert_sweep_bit_identical(snap, r)
 
 
 # ---------------------------------------------------------------------------

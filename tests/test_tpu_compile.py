@@ -25,7 +25,8 @@ from repro.kernels.stream_scatter import scatter_rows
 from repro.stream.snapshot import _scatter_pair
 
 # The layout `DeviceSnapshot` builds for `powerlaw_graph(2**18, 2**22)`: its
-# bucket widths and capacities, tile 256.
+# bucket widths and host capacities, tile 256 (the largest shapes its device
+# arrays can take; they hold the rows in use plus a margin).
 N = 1 << 18
 WIDTHS = (1, 8, 16, 64)
 BUCKET_CAPS = (2 * N, 2 * N, 2 * N, N // 16)
