@@ -74,7 +74,7 @@ def _compact_loop(dg: DeviceGraph, fwd: DeviceGraph, r0, dv0, dn0,
         hi=min(k, dg.n_hi_cap), tiles=kt, dn=kn, fwd_tiles=0)
 
     def body(state):
-        r, dv, dn, _, i, tb = state
+        r, dv, dn, _, i, _, tb = state
         marks, push_ovf = push_expand(fwd, dn, kn)
         dv = jnp.where(i > 0, dv | marks, dv)
         dv_in = dv   # post-expansion frontier entering this sweep (trace)
@@ -99,12 +99,22 @@ def _compact_loop(dg: DeviceGraph, fwd: DeviceGraph, r0, dv0, dn0,
                 tb, i, linf=delta, frontier=frontier,
                 delta_n=jnp.sum(dn_new),
                 pruned=frontier - jnp.sum(dv) if prune else 0)
-        return r_new, dv, dn_new, delta, i + 1, tb
+        return r_new, dv, dn_new, delta, i + 1, af.n_rows, tb
+
+    # Entry: sweep 0 runs where the frontier of sweep 1 (at most the
+    # initial frontier's out-edges, self-loops included) fits K; else the
+    # loop hands off before its first sweep, whose work the overflow of
+    # sweep 1 would waste (a hub's out-edges on a power-law graph). The
+    # initial delta is the overflow signal, read only where the loop does
+    # not enter, so no float64 sentinel has to survive the device (a TPU
+    # v5e reads float64 finfo.max as inf).
+    enter = jnp.sum(jnp.where(dv0, dg.out_deg, 0)) <= k
 
     def cond(state):
         delta, i = state[3], state[4]
-        return (delta > params.tau) & (i < params.max_iter) \
-            & ~jnp.isinf(delta)
+        go = jnp.where(i == 0, enter,
+                       (delta > params.tau) & ~jnp.isinf(delta))
+        return go & (i < params.max_iter)
     # NOTE: body sets delta=inf on ANY capacity overflow (row, tile or
     # worklist), and an overflowing body commits nothing — so the inf check
     # alone routes every overflow to the dense fallback; the old per-cond
@@ -113,11 +123,12 @@ def _compact_loop(dg: DeviceGraph, fwd: DeviceGraph, r0, dv0, dn0,
     tb0 = trace_init(params.max_iter, dt,
                      "dfp_compact" if prune else "df_compact") if trace \
         else jnp.asarray(0, jnp.int32)
-    # finite sentinel: inf is reserved for the capacity-overflow signal
-    init = (r0, dv0, dn0, jnp.asarray(jnp.finfo(dt).max, dt),
-            jnp.asarray(0, jnp.int32), tb0)
-    r, dv, dn, delta, iters, tb = jax.lax.while_loop(cond, body, init)
-    return r, dv, dn, delta, iters, (tb if trace else None)
+    # rows: the active rows of the last sweep tried (the overflowing one
+    # where the loop overflowed)
+    init = (r0, dv0, dn0, jnp.asarray(jnp.inf, dt),
+            jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32), tb0)
+    r, dv, dn, delta, iters, rows, tb = jax.lax.while_loop(cond, body, init)
+    return r, dv, dn, delta, iters, rows, (tb if trace else None)
 
 
 def _df_like_compact(dg, fwd, r_prev, batch: DeviceBatch,
@@ -126,7 +137,8 @@ def _df_like_compact(dg, fwd, r_prev, batch: DeviceBatch,
     """The compact loop, then the dense driver if the frontier outgrew its
     capacity. Host steps run under annotated ``compact.*`` spans; the
     ``compact.*`` counters (batches, overflows, the loop's own sweeps, the
-    planned capacity) take only values the host already reads."""
+    planned capacity, the active rows at the loop's exit, the finish's
+    sweeps) take only values the host reads at the syncs the solve has."""
     obs = _obs()
     n = dg.n
     with obs.span("compact.plan", annotate=True):
@@ -146,34 +158,37 @@ def _df_like_compact(dg, fwd, r_prev, batch: DeviceBatch,
         # graphs, refuting the tile-compaction hypothesis — DESIGN.md §4).
         kt = dg.hi_tiles.shape[0]
         dn0 = jnp.zeros((n,), jnp.bool_)
-    r, dv, dn, delta, iters, tb = _compact_loop(dg, fwd, r_prev, dv, dn0,
-                                                params, k, kt, kn, prune,
-                                                trace)
+    r, dv, dn, delta, iters, rows, tb = _compact_loop(
+        dg, fwd, r_prev, dv, dn0, params, k, kt, kn, prune, trace)
     with obs.span("compact.check", annotate=True):
-        # int(iters) is read on both branches (compact.sweeps); where the
-        # loop converged, the caller's later int() of the same array takes
-        # the host copy JAX keeps on it, so no transfer is added
-        delta_h, sweeps = float(delta), int(iters)
+        # one transfer for the three scalars; where the loop converged, the
+        # caller's later int(iters) takes the host copy JAX keeps on it
+        delta_h, sweeps, rows_h = jax.device_get((delta, iters, rows))
+        delta_h, sweeps, rows_h = float(delta_h), int(sweeps), int(rows_h)
     overflow = delta_h > params.tau and sweeps < params.max_iter
     obs.inc("compact.batches")
     obs.inc("compact.overflows", int(overflow))
     obs.inc("compact.sweeps", sweeps)
     obs.inc("compact.capacity", k)
+    obs.inc("compact.exit_rows", rows_h)
     hw = None
     if overflow:
         # frontier outgrew the capacity: dense engine finishes the job,
         # appending to the same trace buffer at offset `iters`. Its health
         # word (budget = the REMAINING iterations) is the solve's health
-        # word: exhausting `rest` is exactly exhausting the total budget.
-        rest = params._replace(max_iter=params.max_iter - sweeps)
+        # word: exhausting the rest is exactly exhausting the total budget.
         with obs.span("compact.finish", annotate=True):
-            out = list(_dense_finish(dg, r, dv, dn, rest, prune, tb,
+            out = list(_dense_finish(dg, r, dv, dn, params, prune, tb,
                                      jnp.asarray(sweeps, jnp.int32), health))
         if health:
             hw = out.pop()
         r, it2 = out[0], out[1]
         tb = out[2] if trace else None
-        iters = iters + it2
+        # the solve's one read of the finish: its caller reads the sweeps
+        # next, and gets a host int
+        it2 = int(it2)
+        obs.inc("compact.finish_sweeps", it2)
+        iters = sweeps + it2
     elif health:
         hw = solve_health(delta, iters, jnp.sum(r), params)
     res = [r, iters]
@@ -187,6 +202,9 @@ def _df_like_compact(dg, fwd, r_prev, batch: DeviceBatch,
 @functools.partial(jax.jit, static_argnames=("params", "prune", "health"))
 def _dense_finish(dg, r, dv, dn, params, prune, tb=None, i_off=0,
                   health: bool = False):
+    """The dense DF-P loop from the compact loop's state after ``i_off``
+    (an int32 operand) sweeps, for the rest of the budget: one program
+    for every sweep the loop overflows at."""
     return _loop(dg, r, dv, dn, params, expand=True, prune=prune,
                  closed_form=prune, tb=tb, i_off=i_off, health=health)
 

@@ -49,17 +49,19 @@ def batch_to_device(batch, n: int, pad_to: int | None = None) -> DeviceBatch:
 
 
 def solve_health(delta, iters, mass, params: PRParams,
-                 mass_tol: float = MASS_TOL):
+                 mass_tol: float = MASS_TOL, max_iter=None):
     """Health word of a finished solve loop (guard.health), from the final
     L∞ delta / iteration count / rank mass. A +inf delta is a *signal*
     (compact-engine overflow, distributed delta_every skip), not a number —
     clamp it finite so it reads as H_MAX_ITER, not H_NONFINITE; NaN (real
-    poisoning) passes through untouched."""
+    poisoning) passes through untouched. ``max_iter`` (a device scalar may
+    be passed) replaces ``params.max_iter`` as the loop's budget."""
     with jax.named_scope("pr.converge"):
         dt = jnp.asarray(delta).dtype
         delta = jnp.where(jnp.isposinf(delta), jnp.finfo(dt).max, delta)
         return health_word(delta, iters, mass, tau=params.tau,
-                           max_iter=params.max_iter, mass_tol=mass_tol)
+                           max_iter=(params.max_iter if max_iter is None
+                                     else max_iter), mass_tol=mass_tol)
 
 
 def _loop(dg: DeviceGraph, r0: jnp.ndarray, dv0: jnp.ndarray,
@@ -84,7 +86,10 @@ def _loop(dg: DeviceGraph, r0: jnp.ndarray, dv0: jnp.ndarray,
     `tb` (obs.trace.TraceBuffer) switches on iteration telemetry: per-sweep
     L∞, frontier size, δ_N and pruned counts recorded at `i_off + i` — the
     offset lets the compact engine's dense fallback append to the buffer its
-    compact phase started. The rank math never reads the trace."""
+    compact phase started. The rank math never reads the trace. The loop's
+    budget is the rest of `params.max_iter` after those `i_off` sweeps; an
+    `i_off` passed as a device scalar keeps one program for every offset."""
+    budget = params.max_iter - i_off
 
     def body(state):
         r, dv, dn, _, i, tb_, fs = state
@@ -135,7 +140,7 @@ def _loop(dg: DeviceGraph, r0: jnp.ndarray, dv0: jnp.ndarray,
 
     def cond(state):
         delta, i = state[3], state[4]
-        return (delta > params.tau) & (i < params.max_iter)
+        return (delta > params.tau) & (i < budget)
 
     fs_init = fs0 if fs0 is not None else fstats_init(len(dg.buckets))
     init = (r0, dv0, dn0, jnp.asarray(jnp.inf, r0.dtype),
@@ -149,11 +154,11 @@ def _loop(dg: DeviceGraph, r0: jnp.ndarray, dv0: jnp.ndarray,
     if tb is not None:
         out.append(tb_out)
     if health:
-        # iters vs params.max_iter, NOT i_off+iters: a dense finish runs
+        # iters vs the loop's budget, NOT i_off+iters: a dense finish runs
         # with the *remaining* budget, so its own exhaustion is exactly the
         # total budget's exhaustion
         out.append(solve_health(delta, iters, rank_mass(r), params,
-                                mass_tol))
+                                mass_tol, max_iter=budget))
     if caps is not None:
         out.append(fs)
     return tuple(out)
