@@ -127,29 +127,43 @@ def init_ranks(n: int, dtype=jnp.float64) -> jnp.ndarray:
 # Pull primitives (single gather-reduce; one write per vertex)
 # ---------------------------------------------------------------------------
 
+def part_positions(dg: DeviceGraph) -> jnp.ndarray:
+    """[n] int32: each vertex's index in the parts vector, the per-bucket
+    row sums laid end to end and then the hi-slot sums.
+
+    Every vertex has exactly one place, a bucket row or a hi slot, recorded
+    by `bucket_of` / `slot_of`; the offsets are static part lengths, picked
+    by elementwise selects (no table gather).
+    """
+    lens = [blk.rows.shape[0] for blk in dg.buckets]
+    off = jnp.zeros_like(dg.slot_of)
+    for b, start in enumerate(np.cumsum(lens), start=1):
+        off = jnp.where(dg.bucket_of == b, jnp.int32(start), off)
+    return dg.slot_of + off
+
+
 def pull_sum(dg: DeviceGraph, c: jnp.ndarray) -> jnp.ndarray:
     """sum_{u in G'.row(v)} c[u] for every v — the paper's two rank kernels.
 
     ELL side: per degree bucket, [cap_b, w_b] masked gather + row-sum
-    (lane-per-vertex at the bucket's width), scattered once through the
-    bucket's row map. CSR side: [t_cap, tile] masked gather + tile-sum +
-    segment-sum over the tile->row map (tile-loop-per-vertex with an
-    on-chip accumulator on TPU), scattered once into the dense result
-    (drop-mode handles pad sentinels on both sides). Its device ops carry
-    the stage name ``pr.pull`` (a ``jax.named_scope``: op metadata only).
+    (lane-per-vertex at the bucket's width). CSR side: [t_cap, tile] masked
+    gather + tile-sum + segment-sum over the tile->slot map
+    (tile-loop-per-vertex with an on-chip accumulator on TPU). Each vertex
+    then reads its one sum from the parts vector by a single gather
+    (`part_positions`): one write per vertex, no scatter. Its device ops
+    carry the stage name ``pr.pull`` (a ``jax.named_scope``: op metadata
+    only).
     """
     with jax.named_scope("pr.pull"):
         dt = c.dtype
-        out = jnp.zeros(c.shape, dt)
-        for blk in dg.buckets:
-            sums = jnp.sum(jnp.take(c, blk.idx, axis=0)
-                           * blk.mask.astype(dt), axis=1)
-            out = out.at[blk.rows].add(sums, mode="drop")
+        parts = [jnp.sum(jnp.take(c, blk.idx, axis=0)
+                         * blk.mask.astype(dt), axis=1)
+                 for blk in dg.buckets]
         tile_sums = jnp.sum(jnp.take(c, dg.hi_tiles, axis=0)
                             * dg.hi_tmask.astype(dt), axis=1)
-        hi_per_slot = jax.ops.segment_sum(tile_sums, dg.hi_rowmap,
-                                          num_segments=dg.n_hi_cap)
-        return out.at[dg.hi_ids].add(hi_per_slot, mode="drop")
+        parts.append(jax.ops.segment_sum(tile_sums, dg.hi_rowmap,
+                                         num_segments=dg.n_hi_cap))
+        return jnp.take(jnp.concatenate(parts), part_positions(dg))
 
 
 def pull_max(dg: DeviceGraph, x: jnp.ndarray) -> jnp.ndarray:

@@ -5,9 +5,11 @@ instead of on the chip.
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process may load the TPU library, and every test worker
-imports every test file. Where it cannot be described, every test skips.
+imports every test file. Where it cannot be described, every test skips
+but the CPU lowering check of the pull, which needs no topology.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,11 +18,12 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import device_graph, powerlaw_graph
 from repro.core.distributed import _FIELDS, _solver
 from repro.core.dynamic import DeviceBatch, _dfp_pagerank
 from repro.core.frontier import caps_for_parts
 from repro.core.pagerank import DeviceGraph, EllBlock, PRParams, \
-    _static_pagerank
+    _static_pagerank, pull_sum
 from repro.kernels.stream_scatter import scatter_rows
 from repro.stream.snapshot import _scatter_pair
 
@@ -68,11 +71,52 @@ def _graph_shapes(sharding) -> DeviceGraph:
         out_deg=s((N,), jnp.int32))
 
 
-def test_static_pagerank_compiles(one_chip):
+@pytest.fixture(scope="module")
+def static_compiled(one_chip):
     r = jax.ShapeDtypeStruct((N,), jnp.float64, sharding=one_chip)
-    c = _static_pagerank.lower(_graph_shapes(one_chip), r,
-                               params=PARAMS).compile()
-    assert c.memory_analysis().temp_size_in_bytes > 0
+    return _static_pagerank.lower(_graph_shapes(one_chip), r,
+                                  params=PARAMS).compile()
+
+
+def test_static_pagerank_compiles(static_compiled):
+    assert static_compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def _hlo_ops(text, op):
+    """Output types of every `op` instruction in compiled HLO text."""
+    return re.findall(rf"^\s*(?:ROOT )?%\S+ = (.+?) {op}\(", text, re.M)
+
+
+def test_static_pagerank_pull_reads_by_gather(static_compiled):
+    """The pull writes each vertex by one gather: no scatter has an [N]
+    output (the hi side's segment-sum is [N_HI]), and the one pull of the
+    sweep reads [N] by at most one pair of f32 gathers (a float64 value is
+    two f32 words on v5e)."""
+    text = static_compiled.as_text()
+    assert not [t for t in _hlo_ops(text, "scatter") if f"[{N}]" in t]
+    reads = [t for t in _hlo_ops(text, "gather")
+             if t.startswith(f"f32[{N}]{{")]
+    assert 1 <= len(reads) <= 2
+
+
+def _stablehlo_results(text, op):
+    """Result types of every `stablehlo.<op>` in StableHLO text."""
+    return re.findall(rf"stablehlo\.{op}\"?.*?\) -> (tensor<[^>]*>)",
+                      text, re.S)
+
+
+def test_pull_sum_lowers_without_vertex_scatter():
+    """On any backend (no topology needed): `pull_sum` lowers with no
+    scatter over [n] and reads [n] by exactly one gather."""
+    g = powerlaw_graph(1000, 8000, seed=1)
+    dg = device_graph(g, d_p=16, tile=8)
+    parts = [b.rows.shape[0] for b in dg.buckets] + [dg.n_hi_cap]
+    assert len(parts) > 2 and g.n not in parts
+    text = jax.jit(pull_sum).lower(dg, jnp.ones(g.n)).as_text()
+    vec = f"tensor<{g.n}xf64>"
+    scatters = _stablehlo_results(text, "scatter")
+    assert scatters and vec not in scatters       # the segment-sum remains
+    assert _stablehlo_results(text, "gather").count(vec) == 1
 
 
 def test_dfp_pagerank_frontier_caps_compiles(one_chip):
